@@ -1273,6 +1273,8 @@ def main(argv=None) -> None:
     ap.add_argument("--only", metavar="NAME",
                     help="run a single bench by function name")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     benches = SMOKE_BENCHES if args.smoke else BENCHES
     if args.only:
         benches = [b for b in BENCHES if b.__name__ == args.only]
@@ -1284,9 +1286,13 @@ def main(argv=None) -> None:
     for bench in benches:
         bench(smoke=args.smoke)
     if args.json:
+        dev = jax.devices()[0]
         with open(args.json, "w") as f:
             json.dump({"smoke": args.smoke,
                        "backend": jax.default_backend(),
+                       "platform": dev.platform,
+                       "device_kind": dev.device_kind,
+                       "device_count": jax.device_count(),
                        "wall_s": time.time() - t0,
                        "benches": [b.__name__ for b in benches],
                        "rows": ROWS}, f, indent=1)

@@ -85,7 +85,8 @@ def main():
         for si, group in enumerate(shards):
             for r in group:
                 out = sync_and_ack(si, r)
-                print(f"  {r.replica_id}: synced gen={out['gen']} "
+                print(f"  {r.replica_id} (jax on {r.platform}): synced "
+                      f"gen={out['gen']} "
                       f"files={out['files']} bytes={out['bytes']} "
                       f"lag={out['lag_s']*1000:.0f}ms")
         print(f"fleet up: {N_SHARDS} shards x {N_REPLICAS} replica processes")

@@ -41,10 +41,8 @@ from dataclasses import dataclass, field, replace
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 from repro.core import envelope as env
 from repro.core.invert import invert_shard
@@ -57,8 +55,8 @@ from repro.kernels.postings_pack import ref as pack_ref
 
 
 def _flat_device_index(mesh_axis_names, mesh_shape):
-    """Flattened linear device index inside shard_map. Axis sizes come from
-    the (static) mesh shape: ``lax.axis_size`` does not exist on older jax."""
+    """Flattened linear device index inside shard_map, row-major over
+    ``mesh_axis_names`` with sizes from the (static) mesh shape."""
     idx = jnp.int32(0)
     for name in mesh_axis_names:
         idx = idx * mesh_shape[name] + lax.axis_index(name)

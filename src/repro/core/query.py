@@ -82,9 +82,9 @@ class BlockMaxIndex:
     terms: jnp.ndarray            # (T,) sorted
     term_block_start: jnp.ndarray  # (T+1,) CSR into blocks
     idf: jnp.ndarray              # (T,) segment-local idf
-    packed_docs: jnp.ndarray      # (NB, 32, 4)
+    packed_docs: jnp.ndarray      # (NB, 128) words, lane 4*plane + word
     bw_docs: jnp.ndarray          # (NB,)
-    packed_tf: jnp.ndarray        # (NB, 32, 4)
+    packed_tf: jnp.ndarray        # (NB, 128)
     bw_tf: jnp.ndarray            # (NB,)
     first_doc: jnp.ndarray        # (NB,) local (remapped) doc ids
     max_tf: jnp.ndarray           # (NB,)
@@ -110,19 +110,20 @@ class BlockMaxIndex:
     # built before this field existed (bounds fall back to term-level).
     last_doc: jnp.ndarray = None  # (NB,)
     # COMPACT storage layout (fused decompress-and-score): instead of the
-    # fixed-stride (NB, 32, 4) buffers above, keep only the live bit-plane
-    # rows — the exact bytes the storage codec writes — plus per-block row
-    # offsets; selected blocks are expanded inside the scoring computation
-    # (Pallas grid on TPU, jnp gather on CPU). When set, ``packed_docs``/
+    # fixed-stride buffers above, keep only the live bit-plane words — the
+    # exact bytes the storage codec writes, in 128-word rows
+    # (``postings_pack.ref.compact_words``) — plus per-block row offsets;
+    # selected blocks are expanded inside the scoring computation (Pallas
+    # grid on TPU, jnp gather on CPU). When set, ``packed_docs``/
     # ``packed_tf`` are None: the decoded form is never device-resident.
-    cplanes_docs: jnp.ndarray = None  # (sum(bw_docs) + 32, 4) uint32
-    coff_docs: jnp.ndarray = None     # (NB,) first row of each block
-    cplanes_tf: jnp.ndarray = None    # (sum(bw_tf) + 32, 4) uint32
+    cwords_docs: jnp.ndarray = None   # (~sum(bw_docs) / 32 + 2, 128)
+    coff_docs: jnp.ndarray = None     # (NB,) first plane row of each block
+    cwords_tf: jnp.ndarray = None     # (~sum(bw_tf) / 32 + 2, 128)
     coff_tf: jnp.ndarray = None       # (NB,)
 
     @property
     def compact(self) -> bool:
-        return self.cplanes_docs is not None
+        return self.cwords_docs is not None
 
     def packed_bytes(self) -> float:
         return float(pack_ops.packed_bytes(self.bw_docs)
@@ -220,8 +221,8 @@ def _decode_score_blocks(index: BlockMaxIndex, flat, idf_flat, act_flat):
     tf, num) either way — asserted in tests."""
     if index.compact:
         return bm25_blocks_compact(
-            index.cplanes_docs, index.coff_docs[flat], index.bw_docs[flat],
-            index.first_doc[flat], index.cplanes_tf, index.coff_tf[flat],
+            index.cwords_docs, index.coff_docs[flat], index.bw_docs[flat],
+            index.first_doc[flat], index.cwords_tf, index.coff_tf[flat],
             index.bw_tf[flat], idf_flat, act_flat, k1=index.k1)
     return bm25_blocks(
         index.packed_docs[flat], index.bw_docs[flat], index.first_doc[flat],

@@ -54,7 +54,7 @@ from __future__ import annotations
 import collections
 import itertools
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields, replace
 
 import jax
 import jax.numpy as jnp
@@ -87,8 +87,8 @@ _IDX_FIELDS_DENSE = ("terms", "term_block_start", "idf", "packed_docs",
                      "doc_norm", "min_dl", "last_doc")
 _IDX_FIELDS_COMPACT = ("terms", "term_block_start", "idf", "bw_docs",
                        "bw_tf", "first_doc", "max_tf", "doc_norm", "min_dl",
-                       "last_doc", "cplanes_docs", "coff_docs",
-                       "cplanes_tf", "coff_tf")
+                       "last_doc", "cwords_docs", "coff_docs",
+                       "cwords_tf", "coff_tf")
 
 # LRU-bounded: a steady-state serving fleet cycles through a handful of
 # shapes, but a long-lived process that churns through MANY distinct
@@ -200,21 +200,12 @@ def _finish_index(seg: Segment, deltas: np.ndarray, tfs: np.ndarray,
     tbs = np.concatenate([[0], np.cumsum(term_nb)])
     extra = {}
     if compact:
-        # keep only what the storage codec writes: compacted plane rows
-        # (tail-padded with 32 zero rows so in-kernel dynamic 32-row
-        # windows stay in bounds) + each block's first-row offset
-        pad = np.zeros((32, pack_ref.WORDS_PER_PLANE), np.uint32)
-        bwd_np = np.asarray(bwd, np.int64)
-        bwt_np = np.asarray(bwt, np.int64)
-        extra = dict(
-            cplanes_docs=jnp.asarray(np.vstack(
-                [pack_ref.compact_planes(np.asarray(pd), bwd_np), pad])),
-            coff_docs=jnp.asarray(
-                (np.cumsum(bwd_np) - bwd_np).astype(np.int32)),
-            cplanes_tf=jnp.asarray(np.vstack(
-                [pack_ref.compact_planes(np.asarray(pt), bwt_np), pad])),
-            coff_tf=jnp.asarray(
-                (np.cumsum(bwt_np) - bwt_np).astype(np.int32)))
+        # keep only what the storage codec writes: the compacted plane
+        # words in 128-word rows + each block's first plane row
+        wd, cd = pack_ref.compact_words(np.asarray(pd), np.asarray(bwd))
+        wt, ct = pack_ref.compact_words(np.asarray(pt), np.asarray(bwt))
+        extra = dict(cwords_docs=jnp.asarray(wd), coff_docs=jnp.asarray(cd),
+                     cwords_tf=jnp.asarray(wt), coff_tf=jnp.asarray(ct))
         pd = pt = None
     return BlockMaxIndex(
         terms=jnp.asarray(seg.terms.astype(np.int32)),
@@ -397,9 +388,20 @@ class SegmentReader:
 
     @classmethod
     def open(cls, seg: Segment, k1: float = 0.9, b: float = 0.4,
-             compact: bool = False) -> "SegmentReader":
+             compact: bool = False, device=None) -> "SegmentReader":
+        """Build the reader's index on ``device`` (None: JAX's default
+        device now) and commit every device array to it: a jitted
+        evaluator runs where its committed arguments live, so the reader
+        serves from that device whichever thread calls it."""
+        if device is None:
+            device = next(iter(jnp.zeros(()).devices()))
         df_full = np.diff(seg.term_start).astype(np.int64)
-        index = build_block_index(seg, k1, b, compact=compact)
+        with jax.default_device(device):
+            index = build_block_index(seg, k1, b, compact=compact)
+        index = replace(index, **{
+            f.name: jax.device_put(getattr(index, f.name), device)
+            for f in fields(index)
+            if isinstance(getattr(index, f.name), jax.Array)})
         tmax, tmin = _term_impacts(index, seg.n_terms)
         # everything indexed by LOCAL doc slot follows the BP permutation
         # when the segment carries one; the logical arrays stay natural
@@ -408,9 +410,11 @@ class SegmentReader:
         live_local = None
         if seg.has_deletes:
             live_np = ~seg.deletes
-            live_local = jnp.asarray(live_np if r is None else live_np[r])
+            live_local = jax.device_put(live_np if r is None
+                                        else live_np[r], device)
         return cls(seg=seg, index=index,
-                   doc_map=jnp.asarray(doc_ids_local.astype(np.int32)),
+                   doc_map=jax.device_put(doc_ids_local.astype(np.int32),
+                                          device),
                    terms_np=np.asarray(seg.terms),
                    df_np=_live_term_df(seg),
                    nb_np=-(-df_full // BLOCK),
@@ -431,8 +435,9 @@ class SegmentReader:
         live_local = None
         if seg.has_deletes:
             live_np = ~seg.deletes
-            live_local = jnp.asarray(live_np if seg.reorder is None
-                                     else live_np[seg.reorder])
+            live_local = jax.device_put(
+                live_np if seg.reorder is None else live_np[seg.reorder],
+                self.device)
         return SegmentReader(
             seg=seg, index=self.index, doc_map=self.doc_map,
             terms_np=self.terms_np, df_np=_live_term_df(seg),
@@ -443,6 +448,11 @@ class SegmentReader:
                           else seg.doc_len),
             doc_len_local=self.doc_len_local,
             _fns=self._fns)
+
+    @property
+    def device(self):
+        """The device this reader's index is committed to."""
+        return next(iter(self.doc_map.devices()))
 
     @property
     def seg_id(self) -> int:
@@ -973,6 +983,8 @@ class ReaderCache:
     bmw: bool = True     # BMW doc-range-overlap bounds (False: MaxScore)
     midgrid: bool = True  # in-grid theta tightening where gates hold
     compact: bool = False  # fused decompress-and-score index layout
+    device: object = None  # jax.Device readers live on; None = JAX's
+    #                        default device when each reader is built
     builds: int = 0
     hits: int = 0
     reopens: int = 0   # bitmap-only reader swaps (shared core)
@@ -1011,7 +1023,8 @@ class ReaderCache:
                 n_reopened += 1
             else:
                 fresh[seg.seg_id] = SegmentReader.open(
-                    seg, self.k1, self.b, compact=self.compact)
+                    seg, self.k1, self.b, compact=self.compact,
+                    device=self.device)
         with self._lock:
             self.builds += len(fresh) - n_reopened
             self.reopens += n_reopened

@@ -13,34 +13,32 @@ Per grid step (``block_rows`` postings blocks, 128 lanes each), all VPU
 work:
 
   * bit-plane unpack of the lane-blocked PFor doc deltas and tfs
-    (shift/and over the 32x4 packed words);
+    (``postings_pack.kernel.unpack_words``: lane-indexed selects and
+    shifts over each block's 128-word row);
   * a log-step inclusive prefix sum across the 128 lanes rebuilding
     absolute doc ids from the block's first doc;
-  * the fused BM25 numerator idf * (k1+1) * tf;
-  * a RUNNING top-partials accumulator: the per-lane maximum of the
-    length-independent score bound num / (tf + k1*(1-b)) is folded across
-    every grid step into one (1, 128) carry (the output block's index map
-    is constant, so it lives in VMEM for the whole grid) — a device-side
-    record of the best partial any surviving block could contribute,
-    usable as a theta-tightening bound without another pass.
+  * the fused BM25 numerator idf * (k1+1) * tf.
+
+Per-block scalars travel as (n, 1) columns, so every block is a legal
+(multiple-of-8, full) tile; any block count is zero-padded to whole grid
+steps (padding is inactive) and the outputs are sliced back.
 
 The per-doc length norm needs a doc-indexed gather and so stays outside
 the kernel (the caller finishes ``score += num / (tf + doc_norm[doc])``).
 ``ref.py`` is the pure-jnp oracle; parity is asserted in interpret mode
-on CPU (tests/test_kernels.py) and the dispatcher (``ops.py``) compiles
-the real kernel only on TPU.
+on CPU (tests/test_kernels.py), the v5e compile in
+tests/test_tpu_compile.py, and the dispatcher (``ops.py``) runs the
+compiled kernel only on TPU.
 
 ``bm25_blocks_compact_pallas`` is the fused DECOMPRESS-and-score
-variant: the index keeps only the compacted bit-plane rows (``sum(bw)``
-rows of 4 words — byte-identical to what the storage codec writes, see
-``postings_pack.ref.compact_planes``), and each grid step expands its
-blocks' planes from those rows INSIDE the kernel via per-block dynamic
-32-row window loads. The fixed-stride (NB, 32, 4) decoded form never
-materializes in HBM — compressed rows in, scores out. The rows array
-rides a constant index map (resident once for the whole grid) and is
-tail-padded with 32 zero rows so the dynamic windows of the last block
-stay in bounds; planes past a block's width load the NEXT block's rows,
-which ``_unpack_bits``'s width mask zeroes before they contribute.
+variant: the index keeps only the compacted bit-plane words
+(``postings_pack.ref.compact_words``: the storage codec's bytes, cut
+into 128-word rows), which stay in HBM. Each grid step DMAs every
+block's two-row window (a block's at most 128 words start anywhere in
+it) into VMEM, rotates the block's words to lane 0, and runs the shared
+unpack/score body; words past a block's width belong to the next block
+and are masked off by ``unpack_words``. The fixed-stride decoded form
+never materializes in HBM — compressed rows in, scores out.
 """
 from __future__ import annotations
 
@@ -49,67 +47,48 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.postings_pack.kernel import (as_i32, grid_rows, pad_rows,
+                                                unpack_words)
 
 BLOCK = 128
 DEFAULT_BLOCK_ROWS = 128
 
 
-def _unpack_bits(w, bw, R):
-    lane_bit = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, BLOCK // 32, 32), 3)
-    bits = (w[:, :, :, None] >> lane_bit) & jnp.uint32(1)
-    bits = bits.reshape(R, 32, BLOCK)
-    planes = jax.lax.broadcasted_iota(jnp.uint32, (1, 32, 1), 1)
-    valid = planes < bw[:, None, None].astype(jnp.uint32)
-    return jnp.sum(jnp.where(valid, bits, jnp.uint32(0)) << planes, axis=1,
-                   dtype=jnp.uint32)
+def _col(x, n: int, dtype=jnp.int32):
+    """(nb,) per-block vector -> zero-padded (n, 1) column."""
+    return pad_rows(x.astype(dtype).reshape(-1, 1), n)
 
 
-def _bm25_core(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref, idf_ref,
-               act_ref, doc_ref, tf_ref, num_ref, *, k1):
-    """Shared kernel body: unpack + prefix-sum + numerator for one grid
-    step of compacted blocks; returns (act, tf, num) for optional extras."""
-    R = pd_ref.shape[0]
-    deltas = _unpack_bits(pd_ref[...], bwd_ref[...], R).astype(jnp.int32)
-    # inclusive prefix sum over the 128 lanes (log-step doubling)
-    acc = deltas
+def _words(x, n: int):
+    """(nb, 128) or (nb, 32, 4) packed uint32 -> zero-padded (n, 128)."""
+    return pad_rows(as_i32(x.reshape(x.shape[0], BLOCK)), n)
+
+
+def _decode(pd, bwd, first, pt, bwt, idf, *, k1):
+    """Unpack + prefix-sum + numerator for one grid step's blocks:
+    (R, 128) words and (R, 1) columns -> (docids, tf, num), (R, 128)."""
+    acc = unpack_words(pd, bwd)
     shift = 1
-    while shift < BLOCK:
-        shifted = jnp.pad(acc, ((0, 0), (shift, 0)))[:, :BLOCK]
-        acc = acc + shifted
+    while shift < BLOCK:  # inclusive prefix sum over the 128 lanes
+        acc = acc + jnp.pad(acc, ((0, 0), (shift, 0)))[:, :BLOCK]
         shift *= 2
-    docids = first_ref[...][:, None] + acc
-    tf = _unpack_bits(pt_ref[...], bwt_ref[...], R).astype(jnp.float32)
-    num = idf_ref[...][:, None] * (k1 + 1.0) * tf
-    act = (act_ref[...] > 0)[:, None]
-    doc_ref[...] = jnp.where(act, docids, 0)
-    tf_ref[...] = jnp.where(act, tf, 0.0)
-    num_ref[...] = jnp.where(act, num, 0.0)
-    return act, tf, num
+    tf = unpack_words(pt, bwt).astype(jnp.float32)
+    return first + acc, tf, idf * (k1 + 1.0) * tf
+
+
+def _emit(keep, docids, tf, num, doc_ref, tf_ref, num_ref):
+    doc_ref[...] = jnp.where(keep, docids, 0)
+    tf_ref[...] = jnp.where(keep, tf, 0.0)
+    num_ref[...] = jnp.where(keep, num, 0.0)
 
 
 def _bm25_kernel(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref, idf_ref,
                  act_ref, doc_ref, tf_ref, num_ref, *, k1):
-    _bm25_core(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref, idf_ref,
-               act_ref, doc_ref, tf_ref, num_ref, k1=k1)
-
-
-def _bm25_kernel_partials(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref,
-                          idf_ref, act_ref, doc_ref, tf_ref, num_ref,
-                          part_ref, *, k1, b):
-    act, tf, num = _bm25_core(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref,
-                              idf_ref, act_ref, doc_ref, tf_ref, num_ref,
-                              k1=k1)
-    # running top partials: per-lane max of the length-independent score
-    # bound across every surviving block seen so far (constant index map
-    # -> the carry stays resident across the sequential grid)
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        part_ref[...] = jnp.zeros_like(part_ref)
-
-    min_norm = k1 * (1.0 - b)
-    part = jnp.where(act & (tf > 0), num / (tf + min_norm), 0.0)
-    part_ref[...] = jnp.maximum(part_ref[...],
-                                part.max(axis=0, keepdims=True))
+    docids, tf, num = _decode(pd_ref[...], bwd_ref[...], first_ref[...],
+                              pt_ref[...], bwt_ref[...], idf_ref[...], k1=k1)
+    _emit(act_ref[...] > 0, docids, tf, num, doc_ref, tf_ref, num_ref)
 
 
 def _bm25_kernel_midgrid(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref,
@@ -132,161 +111,141 @@ def _bm25_kernel_midgrid(pd_ref, bwd_ref, first_ref, pt_ref, bwt_ref,
     def _init():
         el_ref[...] = theta_ref[...]
 
-    rows = row_ref[...]
-    eq = rows[:, None] == jax.lax.broadcasted_iota(jnp.int32, (R, BLOCK), 1)
-    el = el_ref[...]                                       # (1, 128)
-    l_row = jnp.sum(jnp.where(eq, el, 0.0), axis=1)        # (R,)
+    eq = row_ref[...] == jax.lax.broadcasted_iota(jnp.int32, (R, BLOCK), 1)
+    el = el_ref[...]                                                # (1, 128)
+    l_row = jnp.sum(jnp.where(eq, el, 0.0), axis=1, keepdims=True)  # (R, 1)
     act = act_ref[...] > 0
     skip = act & (ubf_ref[...] < l_row)
     keep = act & ~skip
-    deltas = _unpack_bits(pd_ref[...], bwd_ref[...], R).astype(jnp.int32)
-    acc = deltas
-    shift = 1
-    while shift < BLOCK:
-        shifted = jnp.pad(acc, ((0, 0), (shift, 0)))[:, :BLOCK]
-        acc = acc + shifted
-        shift *= 2
-    docids = first_ref[...][:, None] + acc
-    tf = _unpack_bits(pt_ref[...], bwt_ref[...], R).astype(jnp.float32)
-    num = idf_ref[...][:, None] * (k1 + 1.0) * tf
-    keep2 = keep[:, None]
-    doc_ref[...] = jnp.where(keep2, docids, 0)
-    tf_ref[...] = jnp.where(keep2, tf, 0.0)
-    num_ref[...] = jnp.where(keep2, num, 0.0)
+    docids, tf, num = _decode(pd_ref[...], bwd_ref[...], first_ref[...],
+                              pt_ref[...], bwt_ref[...], idf_ref[...], k1=k1)
+    _emit(keep, docids, tf, num, doc_ref, tf_ref, num_ref)
     skip_ref[...] = skip.astype(jnp.int32)
     # fold the kept blocks' k-th-best witnesses into the carry: per block
     # k-1 rounds of (max, retire ties), floored at 0 — every positive
     # lane is a distinct doc, so the result is witnessed by k docs
-    part = jnp.where(keep2 & (tf > 0), num / (tf + nmax_ref[0, 0]), 0.0)
-    cur = part
+    cur = jnp.where(keep & (tf > 0), num / (tf + nmax_ref[...]), 0.0)
     for _ in range(max(k - 1, 0)):
-        m = cur.max(axis=1, keepdims=True)
-        cur = jnp.where(cur == m, -1.0, cur)
-    kth = jnp.maximum(cur.max(axis=1), 0.0)
-    el_ref[...] = jnp.maximum(el, jnp.where(eq, kth[:, None], 0.0
+        cur = jnp.where(cur == cur.max(axis=1, keepdims=True), -1.0, cur)
+    kth = jnp.maximum(cur.max(axis=1, keepdims=True), 0.0)          # (R, 1)
+    el_ref[...] = jnp.maximum(el, jnp.where(eq, kth, 0.0
                                             ).max(axis=0, keepdims=True))
 
 
-def _expand_rows(cpl_ref, off, R):
-    """In-kernel expansion of compacted bit-plane rows: R dynamic
-    (32, 4)-row window loads from the resident rows array. Garbage
-    planes (rows past a narrow block's width belong to the next block)
-    are NOT masked here — ``_unpack_bits``'s ``plane < bw`` mask already
-    zeroes them before they contribute."""
-    def body(i, acc):
-        rows = pl.load(cpl_ref, (pl.ds(off[i], 32), slice(None)))
-        return jax.lax.dynamic_update_slice(acc, rows[None], (i, 0, 0))
-    return jax.lax.fori_loop(
-        0, R, body, jnp.zeros((R, 32, BLOCK // 32), jnp.uint32))
+def _gather_windows(coff_ref, base, R, words_hbm, win, al, sem):
+    """DMA each of this step's R blocks' two-row word windows from HBM
+    (all in flight at once), then rotate each block's words to lane 0:
+    ``al[i]`` becomes block i's 128 words in the fixed-stride layout."""
+    def start(i, c):
+        row = (coff_ref[base + i] * 4) // BLOCK
+        pltpu.make_async_copy(words_hbm.at[pl.ds(row, 2)], win.at[i],
+                              sem.at[0]).start()
+        return c
+
+    def wait(i, c):
+        pltpu.make_async_copy(words_hbm.at[pl.ds(0, 2)], win.at[i],
+                              sem.at[0]).wait()
+        return c
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, BLOCK), 1)
+
+    def realign(i, c):
+        s = (coff_ref[base + i] * 4) % BLOCK
+        w = win[i]
+        rot = (BLOCK - s) % BLOCK
+        al[pl.ds(i, 1), :] = jnp.where(lane < BLOCK - s,
+                                       pltpu.roll(w[0:1, :], rot, 1),
+                                       pltpu.roll(w[1:2, :], rot, 1))
+        return c
+
+    jax.lax.fori_loop(0, R, start, 0)
+    jax.lax.fori_loop(0, R, wait, 0)
+    jax.lax.fori_loop(0, R, realign, 0)
 
 
-def _bm25_compact_kernel(cpld_ref, cplt_ref, coffd_ref, bwd_ref, first_ref,
-                         cofft_ref, bwt_ref, idf_ref, act_ref,
-                         doc_ref, tf_ref, num_ref, *, k1):
-    """Fused decompress-and-score grid step: expand this step's blocks
-    from the compressed rows, then the shared unpack/prefix-sum/score
-    body. Mirrors ``_bm25_core`` with the expansion fused in front."""
-    R = coffd_ref.shape[0]
-    pd = _expand_rows(cpld_ref, coffd_ref[...], R)
-    pt = _expand_rows(cplt_ref, cofft_ref[...], R)
-    deltas = _unpack_bits(pd, bwd_ref[...], R).astype(jnp.int32)
-    acc = deltas
-    shift = 1
-    while shift < BLOCK:
-        shifted = jnp.pad(acc, ((0, 0), (shift, 0)))[:, :BLOCK]
-        acc = acc + shifted
-        shift *= 2
-    docids = first_ref[...][:, None] + acc
-    tf = _unpack_bits(pt, bwt_ref[...], R).astype(jnp.float32)
-    num = idf_ref[...][:, None] * (k1 + 1.0) * tf
-    act = (act_ref[...] > 0)[:, None]
-    doc_ref[...] = jnp.where(act, docids, 0)
-    tf_ref[...] = jnp.where(act, tf, 0.0)
-    num_ref[...] = jnp.where(act, num, 0.0)
+def _bm25_compact_kernel(coffd_ref, cofft_ref, wd_hbm, wt_hbm, bwd_ref,
+                         first_ref, bwt_ref, idf_ref, act_ref, doc_ref,
+                         tf_ref, num_ref, win, al_d, al_t, sem, *, k1):
+    """Fused decompress-and-score grid step: fetch this step's blocks
+    from the compressed words in HBM, then the shared decode body."""
+    R = doc_ref.shape[0]
+    base = pl.program_id(0) * R
+    _gather_windows(coffd_ref, base, R, wd_hbm, win, al_d, sem)
+    _gather_windows(cofft_ref, base, R, wt_hbm, win, al_t, sem)
+    docids, tf, num = _decode(al_d[...], bwd_ref[...], first_ref[...],
+                              al_t[...], bwt_ref[...], idf_ref[...], k1=k1)
+    _emit(act_ref[...] > 0, docids, tf, num, doc_ref, tf_ref, num_ref)
+
+
+def _lane_out(n):
+    return [jax.ShapeDtypeStruct((n, BLOCK), jnp.int32),
+            jax.ShapeDtypeStruct((n, BLOCK), jnp.float32),
+            jax.ShapeDtypeStruct((n, BLOCK), jnp.float32)]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k1", "block_rows", "interpret"))
-def bm25_blocks_compact_pallas(cplanes_docs, coff_docs, bw_docs, first_doc,
-                               cplanes_tf, coff_tf, bw_tf, idf, active, *,
+def bm25_blocks_compact_pallas(cwords_docs, coff_docs, bw_docs, first_doc,
+                               cwords_tf, coff_tf, bw_tf, idf, active, *,
                                k1: float = 0.9,
                                block_rows: int = DEFAULT_BLOCK_ROWS,
-                               interpret: bool = True):
-    """-> (docids, tf, num) each (S, 128), S the compacted survivor
-    count, decoding the selected blocks from the COMPACT rows arrays
-    inside the grid. ``cplanes_docs``/``cplanes_tf`` are the whole
-    index's (P, 4) compressed plane rows (tail-padded with 32 zero rows
-    by the builder); ``coff_*``/``bw_*``/``first_doc``/``idf``/
-    ``active`` are (S,) per-selected-block vectors."""
+                               interpret: bool):
+    """-> (docids, tf, num) each (S, 128), S the selected block count,
+    decoding the selected blocks from the COMPACT word rows inside the
+    grid. ``cwords_docs``/``cwords_tf`` are the whole index's (M, 128)
+    compact words (``postings_pack.ref.compact_words``), left in HBM;
+    ``coff_*``/``bw_*``/``first_doc``/``idf``/``active`` are (S,)
+    per-selected-block vectors."""
     nb = coff_docs.shape[0]
-    block_rows = min(block_rows, nb)
-    assert nb % block_rows == 0, (nb, block_rows)
-    grid = (nb // block_rows,)
-    vec = lambda: pl.BlockSpec((block_rows,), lambda i: (i,))
-    lanes = lambda: pl.BlockSpec((block_rows, BLOCK), lambda i: (i, 0))
-    rows = lambda n: pl.BlockSpec((n, BLOCK // 32), lambda i: (0, 0))
-    return pl.pallas_call(
+    br, n = grid_rows(nb, block_rows)
+    col = pl.BlockSpec((br, 1), lambda i, *_: (i, 0))
+    lanes = pl.BlockSpec((br, BLOCK), lambda i, *_: (i, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
         functools.partial(_bm25_compact_kernel, k1=k1),
-        grid=grid,
-        in_specs=[rows(cplanes_docs.shape[0]), rows(cplanes_tf.shape[0]),
-                  vec(), vec(), vec(), vec(), vec(), vec(), vec()],
-        out_specs=[lanes(), lanes(), lanes()],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n // br,),
+            in_specs=[hbm, hbm, col, col, col, col, col],
+            out_specs=[lanes, lanes, lanes],
+            scratch_shapes=[pltpu.VMEM((br, 2, BLOCK), jnp.int32),
+                            pltpu.VMEM((br, BLOCK), jnp.int32),
+                            pltpu.VMEM((br, BLOCK), jnp.int32),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=_lane_out(n),
         interpret=interpret,
-    )(cplanes_docs.astype(jnp.uint32), cplanes_tf.astype(jnp.uint32),
-      coff_docs.astype(jnp.int32), bw_docs.astype(jnp.int32),
-      first_doc.astype(jnp.int32), coff_tf.astype(jnp.int32),
-      bw_tf.astype(jnp.int32), idf.astype(jnp.float32),
-      active.astype(jnp.int32))
+    )(pad_rows(coff_docs.astype(jnp.int32), n),
+      pad_rows(coff_tf.astype(jnp.int32), n),
+      as_i32(cwords_docs), as_i32(cwords_tf),
+      _col(bw_docs, n), _col(first_doc, n), _col(bw_tf, n),
+      _col(idf, n, jnp.float32), _col(active, n))
+    return [o[:nb] for o in out]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k1", "b", "block_rows", "interpret",
-                                    "partials"))
+                   static_argnames=("k1", "block_rows", "interpret"))
 def bm25_blocks_pallas(packed_docs, bw_docs, first_doc, packed_tf, bw_tf,
-                       idf, active, *, k1: float = 0.9, b: float = 0.4,
+                       idf, active, *, k1: float = 0.9,
                        block_rows: int = DEFAULT_BLOCK_ROWS,
-                       interpret: bool = True, partials: bool = False):
-    """-> (docids, tf, num) each (NB, 128); with ``partials=True`` also
-    the (1, 128) running per-lane top-partial bound (the hot serving path
-    compiles without it — nothing reads the carry there). NB is the
-    COMPACTED survivor count (bucket-padded to a power of two by the
-    caller, so ``block_rows`` always divides it)."""
+                       interpret: bool):
+    """-> (docids, tf, num) each (NB, 128). NB is any block count: the
+    compacted survivor bucket on the pruned path, the whole candidate
+    grid on the exhaustive one. Packed blocks are (NB, 128) words or
+    their (NB, 32, 4) form."""
     nb = packed_docs.shape[0]
-    block_rows = min(block_rows, nb)
-    assert nb % block_rows == 0, (nb, block_rows)
-    grid = (nb // block_rows,)
-    vec = lambda: pl.BlockSpec((block_rows,), lambda i: (i,))
-    packed = lambda: pl.BlockSpec((block_rows, 32, 4), lambda i: (i, 0, 0))
-    lanes = lambda: pl.BlockSpec((block_rows, BLOCK), lambda i: (i, 0))
-    carry = lambda: pl.BlockSpec((1, BLOCK), lambda i: (0, 0))
-    out_specs = [lanes(), lanes(), lanes()]
-    out_shape = [
-        jax.ShapeDtypeStruct((nb, BLOCK), jnp.int32),
-        jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-        jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-    ]
-    if partials:
-        kernel = functools.partial(_bm25_kernel_partials, k1=k1, b=b)
-        out_specs.append(carry())
-        out_shape.append(jax.ShapeDtypeStruct((1, BLOCK), jnp.float32))
-    else:
-        kernel = functools.partial(_bm25_kernel, k1=k1)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[packed(), vec(), vec(), packed(), vec(), vec(), vec()],
-        out_specs=out_specs,
-        out_shape=out_shape,
+    br, n = grid_rows(nb, block_rows)
+    col = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    lanes = pl.BlockSpec((br, BLOCK), lambda i: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(_bm25_kernel, k1=k1),
+        grid=(n // br,),
+        in_specs=[lanes, col, col, lanes, col, col, col],
+        out_specs=[lanes] * 3,
+        out_shape=_lane_out(n),
         interpret=interpret,
-    )(packed_docs.astype(jnp.uint32), bw_docs.astype(jnp.int32),
-      first_doc.astype(jnp.int32), packed_tf.astype(jnp.uint32),
-      bw_tf.astype(jnp.int32), idf.astype(jnp.float32),
-      active.astype(jnp.int32))
+    )(_words(packed_docs, n), _col(bw_docs, n), _col(first_doc, n),
+      _words(packed_tf, n), _col(bw_tf, n), _col(idf, n, jnp.float32),
+      _col(active, n))
+    return [o[:nb] for o in out]
 
 
 @functools.partial(jax.jit,
@@ -295,7 +254,7 @@ def bm25_blocks_midgrid_pallas(packed_docs, bw_docs, first_doc, packed_tf,
                                bw_tf, idf, active, rows, ubf, theta_lanes,
                                norm_max, *, k1: float = 0.9, k: int = 10,
                                block_rows: int = 8,
-                               interpret: bool = True):
+                               interpret: bool):
     """-> (docids, tf, num, skip): the plain kernel's outputs with
     midgrid-skipped blocks zeroed, plus the per-block (S,) skip flags.
     ``rows`` attributes each compacted block to its query row, ``ubf``
@@ -305,32 +264,24 @@ def bm25_blocks_midgrid_pallas(packed_docs, bw_docs, first_doc, packed_tf,
     realized partial for every lane. Defaults to a SHORT grid step so the
     carry feeds back within typical survivor buckets."""
     nb = packed_docs.shape[0]
-    block_rows = min(block_rows, nb)
-    assert nb % block_rows == 0, (nb, block_rows)
-    grid = (nb // block_rows,)
-    vec = lambda: pl.BlockSpec((block_rows,), lambda i: (i,))
-    packed = lambda: pl.BlockSpec((block_rows, 32, 4), lambda i: (i, 0, 0))
-    lanes = lambda: pl.BlockSpec((block_rows, BLOCK), lambda i: (i, 0))
-    carry = lambda: pl.BlockSpec((1, BLOCK), lambda i: (0, 0))
-    scalar = lambda: pl.BlockSpec((1, 1), lambda i: (0, 0))
+    br, n = grid_rows(nb, block_rows)
+    col = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    lanes = pl.BlockSpec((br, BLOCK), lambda i: (i, 0))
+    carry = pl.BlockSpec((1, BLOCK), lambda i: (0, 0))
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
     out = pl.pallas_call(
         functools.partial(_bm25_kernel_midgrid, k1=k1, k=k),
-        grid=grid,
-        in_specs=[packed(), vec(), vec(), packed(), vec(), vec(), vec(),
-                  vec(), vec(), carry(), scalar()],
-        out_specs=[lanes(), lanes(), lanes(), vec(), carry()],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-            jax.ShapeDtypeStruct((1, BLOCK), jnp.float32),
-        ],
+        grid=(n // br,),
+        in_specs=[lanes, col, col, lanes, col, col, col, col, col, carry,
+                  scalar],
+        out_specs=[lanes, lanes, lanes, col, carry],
+        out_shape=_lane_out(n) + [
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, BLOCK), jnp.float32)],
         interpret=interpret,
-    )(packed_docs.astype(jnp.uint32), bw_docs.astype(jnp.int32),
-      first_doc.astype(jnp.int32), packed_tf.astype(jnp.uint32),
-      bw_tf.astype(jnp.int32), idf.astype(jnp.float32),
-      active.astype(jnp.int32), rows.astype(jnp.int32),
-      ubf.astype(jnp.float32), theta_lanes.astype(jnp.float32),
+    )(_words(packed_docs, n), _col(bw_docs, n), _col(first_doc, n),
+      _words(packed_tf, n), _col(bw_tf, n), _col(idf, n, jnp.float32),
+      _col(active, n), _col(rows, n), _col(ubf, n, jnp.float32),
+      theta_lanes.astype(jnp.float32),
       jnp.asarray(norm_max, jnp.float32).reshape(1, 1))
-    return out[:4]
+    return [o[:nb] for o in out[:3]] + [out[3][:nb, 0]]

@@ -41,23 +41,23 @@ def bm25_blocks(packed_docs, bw_docs, first_doc, packed_tf, bw_tf, idf,
                            idf, active, k1=k1)
 
 
-def bm25_blocks_compact(cplanes_docs, coff_docs, bw_docs, first_doc,
-                        cplanes_tf, coff_tf, bw_tf, idf, active, *,
+def bm25_blocks_compact(cwords_docs, coff_docs, bw_docs, first_doc,
+                        cwords_tf, coff_tf, bw_tf, idf, active, *,
                         k1: float = 0.9):
     """Fused decompress-and-score over the COMPACT index layout: the
     selected blocks are decoded straight from the compressed bit-plane
-    rows. On TPU the Pallas grid expands each block's rows in-kernel
+    words. On TPU the Pallas grid fetches each block's words in-kernel
     (the decoded fixed-stride form never round-trips through HBM);
     elsewhere the jnp reference gathers + expands per selected block
     inside the same jitted computation — survivor-proportional on the
     compacted pruned path because the caller compacted first."""
     if jax.default_backend() == "tpu":
-        return bm25_blocks_compact_pallas(cplanes_docs, coff_docs, bw_docs,
-                                          first_doc, cplanes_tf, coff_tf,
+        return bm25_blocks_compact_pallas(cwords_docs, coff_docs, bw_docs,
+                                          first_doc, cwords_tf, coff_tf,
                                           bw_tf, idf, active, k1=k1,
                                           interpret=False)
-    return bm25_blocks_compact_ref(cplanes_docs, coff_docs, bw_docs,
-                                   first_doc, cplanes_tf, coff_tf, bw_tf,
+    return bm25_blocks_compact_ref(cwords_docs, coff_docs, bw_docs,
+                                   first_doc, cwords_tf, coff_tf, bw_tf,
                                    idf, active, k1=k1)
 
 
@@ -79,15 +79,3 @@ def bm25_blocks_midgrid(packed_docs, bw_docs, first_doc, packed_tf, bw_tf,
         rows, ubf, theta_lanes, norm_max, k1=k1, k=k,
         block_rows=block_rows)
 
-
-def bm25_blocks_partials(packed_docs, bw_docs, first_doc, packed_tf, bw_tf,
-                         idf, active, *, k1: float = 0.9, b: float = 0.4,
-                         interpret: bool = None):
-    """Full kernel output including the (1, 128) running per-lane
-    top-partial bound (see kernel docstring). ``interpret`` defaults to
-    interpret-mode everywhere but TPU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return bm25_blocks_pallas(packed_docs, bw_docs, first_doc, packed_tf,
-                              bw_tf, idf, active, k1=k1, b=b,
-                              interpret=interpret, partials=True)

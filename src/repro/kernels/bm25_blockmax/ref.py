@@ -11,20 +11,19 @@ The caller finishes the score with the per-doc length norm:
   score += num / (tf + k1 * (1 - b + b * dl[doc] / avgdl))
 which needs a doc-indexed gather and so lives outside the kernel.
 
-``lane_partials_ref`` is the oracle for the Pallas kernel's running
-top-partials carry: the per-lane maximum over all active blocks of the
-length-independent score bound num / (tf + k1*(1-b)).
+Packed blocks arrive in the index's device layout, (NB, 128) words
+(lane ``4 * plane + word``), or in the equivalent (NB, 32, 4) form.
 
 ``bm25_blocks_compact_ref`` is the fused decompress-and-score oracle
-over the COMPACT storage layout: instead of the fixed-stride
-(NB, 32, 4) buffer, the index holds only the live bit-plane rows
-(``sum(bw)`` rows of 4 words — the exact bytes the storage codec
-writes) plus per-block row offsets. Each selected block's planes are
-gathered straight out of the compressed rows and expanded inside the
-(jitted) computation — on CPU this is the jnp-over-compacted fallback
-that decodes per survivor block; on TPU the Pallas variant does the
-same expansion inside the kernel grid, so the fixed-stride decoded
-form never exists in HBM.
+over the COMPACT storage layout: instead of the fixed-stride buffer,
+the index holds only the live bit-plane words (``sum(bw)`` rows of 4
+words — the exact bytes the storage codec writes, cut into 128-word
+rows by ``postings_pack.ref.compact_words``) plus per-block row
+offsets. Each selected block's planes are gathered straight out of the
+compressed words and expanded inside the (jitted) computation — on CPU
+this is the jnp-over-compacted fallback that decodes per survivor
+block; on TPU the Pallas variant does the same expansion inside the
+kernel grid, so the fixed-stride decoded form never exists in HBM.
 """
 from __future__ import annotations
 
@@ -33,13 +32,19 @@ import jax.numpy as jnp
 
 from repro.kernels.postings_pack.ref import unpack_fast
 
+BLOCK = 128
+
+
+def _unpack(packed, bw):
+    return unpack_fast(packed.reshape(-1, 32, 4), bw)
+
 
 def bm25_blocks_ref(packed_docs, bw_docs, first_doc, packed_tf, bw_tf,
                     idf, active, k1: float = 0.9):
     """-> (docids (NB,128) int32, tf (NB,128) f32, num (NB,128) f32)."""
-    deltas = unpack_fast(packed_docs, bw_docs).astype(jnp.int32)
+    deltas = _unpack(packed_docs, bw_docs).astype(jnp.int32)
     docids = first_doc[:, None] + jnp.cumsum(deltas, axis=1)
-    tf = unpack_fast(packed_tf, bw_tf).astype(jnp.float32)
+    tf = _unpack(packed_tf, bw_tf).astype(jnp.float32)
     num = idf[:, None] * (k1 + 1.0) * tf
     act = (active > 0)[:, None]
     return (jnp.where(act, docids, 0),
@@ -47,45 +52,33 @@ def bm25_blocks_ref(packed_docs, bw_docs, first_doc, packed_tf, bw_tf,
             jnp.where(act, num, 0.0))
 
 
-def expand_rows_ref(cplanes, coff, bw):
-    """Gather-expand compacted bit-plane rows into the fixed-stride form.
+def expand_rows_ref(cwords, coff, bw):
+    """Gather-expand compacted bit-plane words into the fixed-stride form.
 
-    ``cplanes`` (P, 4) uint32 holds every block's live planes
-    back-to-back (block-major, plane-major — ``compact_planes``' output,
-    padded with 32 zero rows at the tail so dynamic 32-row windows never
-    read out of bounds); ``coff`` (S,) is each selected block's first
-    row; ``bw`` (S,) its plane count. Returns (S, 32, 4) uint32 with
-    dead planes (>= bw) zeroed, exactly what ``unpack_fast`` consumes.
+    ``cwords`` (M, 128) uint32 is every block's live planes back to back
+    as one word stream (``postings_pack.ref.compact_words``: block-major,
+    plane-major, zero-padded past the end); ``coff`` (S,) is each
+    selected block's first plane row (its words start at ``4 * coff``);
+    ``bw`` (S,) its plane count. Returns (S, 32, 4) uint32 with dead
+    planes (>= bw) zeroed, exactly what ``unpack_fast`` consumes.
     """
-    j = jnp.arange(32)
-    valid = j[None, :] < bw[:, None]
-    rows = jnp.where(valid, coff[:, None] + j[None, :], 0)
-    w = cplanes[rows]                                   # (S, 32, 4)
-    return jnp.where(valid[:, :, None], w, jnp.uint32(0))
+    j = jnp.arange(BLOCK)
+    valid = (j // 4)[None, :] < bw[:, None]
+    w = cwords.reshape(-1)[jnp.where(valid, coff[:, None] * 4 + j, 0)]
+    return jnp.where(valid, w, jnp.uint32(0)).reshape(-1, 32, 4)
 
 
-def bm25_blocks_compact_ref(cplanes_docs, coff_docs, bw_docs, first_doc,
-                            cplanes_tf, coff_tf, bw_tf, idf, active,
+def bm25_blocks_compact_ref(cwords_docs, coff_docs, bw_docs, first_doc,
+                            cwords_tf, coff_tf, bw_tf, idf, active,
                             k1: float = 0.9):
     """Fused decompress-and-score over compact storage: expand the
-    selected blocks' planes from the compressed rows, then the standard
+    selected blocks' planes from the compressed words, then the standard
     block scoring — same (docids, tf, num) contract as
     ``bm25_blocks_ref``, asserted bit-identical in tests."""
-    pd = expand_rows_ref(cplanes_docs, coff_docs, bw_docs)
-    pt = expand_rows_ref(cplanes_tf, coff_tf, bw_tf)
+    pd = expand_rows_ref(cwords_docs, coff_docs, bw_docs)
+    pt = expand_rows_ref(cwords_tf, coff_tf, bw_tf)
     return bm25_blocks_ref(pd, bw_docs, first_doc, pt, bw_tf, idf, active,
                            k1=k1)
-
-
-def lane_partials_ref(tf, num, k1: float = 0.9, b: float = 0.4):
-    """(1, 128) per-lane max of num / (tf + min_norm) over active blocks
-    (``tf``/``num`` already masked to zero on inactive blocks)."""
-    min_norm = k1 * (1.0 - b)
-    part = jnp.where(tf > 0, num / (tf + min_norm), 0.0)
-    return part.max(axis=0, keepdims=True)
-
-
-BLOCK = 128
 
 
 def _kth_lane_partial(part, k: int):
@@ -123,10 +116,10 @@ def bm25_blocks_midgrid_ref(packed_docs, bw_docs, first_doc, packed_tf,
     S = packed_docs.shape[0]
     block_rows = min(block_rows, S)
     assert S % block_rows == 0, (S, block_rows)
-    deltas = unpack_fast(packed_docs, bw_docs).astype(jnp.int32)
+    deltas = _unpack(packed_docs, bw_docs).astype(jnp.int32)
     docids = first_doc.astype(jnp.int32)[:, None] + jnp.cumsum(deltas,
                                                                axis=1)
-    tf_all = unpack_fast(packed_tf, bw_tf).astype(jnp.float32)
+    tf_all = _unpack(packed_tf, bw_tf).astype(jnp.float32)
     num_all = idf.astype(jnp.float32)[:, None] * (k1 + 1.0) * tf_all
     act_all = active > 0
     eq_all = rows.astype(jnp.int32)[:, None] \

@@ -72,7 +72,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
     jax.jit, static_argnames=("causal", "window", "softcap", "block_q",
                               "block_kv", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    block_q=128, block_kv=128, interpret=True):
+                    block_q=128, block_kv=128, interpret: bool):
     """q: (B, Sq, H, D); k, v: (B, Skv, KVH, D) -> (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     _, Skv, KVH, _ = k.shape

@@ -1,6 +1,7 @@
 """Public ops for postings packing: jit'd wrappers that dispatch to the
-Pallas kernel (TPU, or interpret mode for validation) or the pure-jnp
-reference (CPU default — identical math, XLA-fused)."""
+compiled Pallas kernel on TPU or the pure-jnp reference elsewhere
+(identical math, XLA-fused). Packed blocks come back in the device
+layout, (nb, 128) words with lane ``4 * plane + word``."""
 from __future__ import annotations
 
 import jax
@@ -16,31 +17,24 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def pad_to_blocks(stream: jnp.ndarray, fill: int = 0):
-    """(n,) -> ((nb, 128), n) padding the tail with ``fill``."""
-    n = stream.shape[0]
-    nb = -(-n // BLOCK)
-    padded = jnp.full((nb * BLOCK,), fill, stream.dtype).at[:n].set(stream)
-    return padded.reshape(nb, BLOCK), n
-
-
 @jax.jit
 def pack(deltas: jnp.ndarray):
-    """deltas: (nb, 128) uint32 -> (packed (nb,32,4), bw (nb,))."""
+    """deltas: (nb, 128) uint32 -> (packed (nb, 128), bw (nb,))."""
     if _on_tpu():
-        return tuple(pack_pallas(deltas, interpret=False))
+        return pack_pallas(deltas, interpret=False)
     # pack_ref, not pack_fast: XLA fuses the broadcast form into one pass,
     # which wins at the large nb of whole-segment builds (the transpose
     # form wins for the small-nb unpacks of the query path).
-    return ref.pack_ref(deltas)
+    packed, bw = ref.pack_ref(deltas)
+    return packed.reshape(deltas.shape[0], BLOCK), bw
 
 
 @jax.jit
 def unpack(packed: jnp.ndarray, bw: jnp.ndarray):
+    """(nb, 128) packed words + (nb,) -> (nb, 128) uint32 values."""
     if _on_tpu():
         return unpack_pallas(packed, bw, interpret=False)
-    return ref.unpack_fast(packed, bw)  # == unpack_ref
+    return ref.unpack_fast(packed.reshape(-1, 32, ref.WORDS_PER_PLANE), bw)
 
 
 packed_bytes = ref.packed_bytes
-bit_width = ref.bit_width

@@ -14,6 +14,7 @@ fixed-stride blocks that a second pass compacts.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 BLOCK = 128
@@ -57,67 +58,88 @@ def unpack_ref(packed: jnp.ndarray, bw: jnp.ndarray):
     return vals.sum(axis=1, dtype=jnp.uint32)
 
 
-def _bit_transpose32(x: jnp.ndarray) -> jnp.ndarray:
+def _xp(x):
+    """numpy for host arrays (the storage codec), jax.numpy otherwise."""
+    return np if isinstance(x, np.ndarray) else jnp
+
+
+def _bit_transpose32(x):
     """(..., 32) uint32 bit-matrix transpose: out[..., t] bit j ==
     x[..., j] bit t. Hacker's Delight transpose32, vectorized over the
     leading axes — 5 mask/shift stages instead of materializing the
     (..., 32, 32) bit tensor."""
+    xp = _xp(x)
     j = 16
-    m = jnp.uint32(0x0000FFFF)
+    m = np.uint32(0x0000FFFF)
     while j:
         xs = x.reshape(x.shape[:-1] + (x.shape[-1] // (2 * j), 2, j))
         hi, lo = xs[..., 0, :], xs[..., 1, :]
-        t = ((hi >> j) ^ lo) & m
-        hi, lo = hi ^ (t << j), lo ^ t
-        x = jnp.stack([hi, lo], axis=-2).reshape(x.shape)
+        t = ((hi >> np.uint32(j)) ^ lo) & m
+        hi, lo = hi ^ (t << np.uint32(j)), lo ^ t
+        x = xp.stack([hi, lo], axis=-2).reshape(x.shape)
         j >>= 1
-        m = m ^ (m << j)
+        m = m ^ (m << np.uint32(j))
     return x
 
 
-def pack_fast(deltas: jnp.ndarray):
-    """Exact-equivalent of ``pack_ref`` (asserted in tests) via bit-plane
-    transpose. No bw masking is needed: bw = bits(block max), so every
-    value's planes >= bw are zero already."""
+def pack_fast(deltas: np.ndarray):
+    """Host-side exact equivalent of ``pack_ref`` (asserted in tests) via
+    bit-plane transpose, numpy in and out: the storage codec's pack. No
+    bw masking is needed: bw = bits(block max), so every value's planes
+    >= bw are zero already."""
     assert deltas.shape[-1] == BLOCK, deltas.shape
-    d = deltas.astype(jnp.uint32)
+    d = np.asarray(deltas, np.uint32)
     nb = d.shape[0]
-    bw = bit_width(d.max(axis=-1))
+    bw = np.frexp(d.max(axis=-1).astype(np.float64))[1].astype(np.int32)
     lanes = d.reshape(nb, WORDS_PER_PLANE, 32)     # [w, t] = lane 32w+t
     planes = _bit_transpose32(lanes)               # [w, j]: bit t = lane bit j
-    return jnp.swapaxes(planes, -2, -1), bw        # (nb, 32, 4)
+    return np.swapaxes(planes, -2, -1), bw         # (nb, 32, 4)
 
 
-def unpack_fast(packed: jnp.ndarray, bw: jnp.ndarray) -> jnp.ndarray:
+def unpack_fast(packed, bw):
     """Exact-equivalent of ``unpack_ref`` (asserted in tests) via bit-plane
     transpose — the hot read-path unpack. Requires planes >= bw to be zero,
     which ``pack_ref``/the device kernel guarantee, so ``bw`` is not needed
-    to mask (kept for signature parity)."""
+    to mask (kept for signature parity). numpy in, numpy out; jax
+    otherwise."""
     del bw
     nb = packed.shape[0]
-    planes_last = jnp.swapaxes(packed, -2, -1)      # (nb, 4, 32)
+    planes_last = _xp(packed).swapaxes(packed, -2, -1)  # (nb, 4, 32)
     vals = _bit_transpose32(planes_last)            # lane 32w+t at [, w, t]
     return vals.reshape(nb, BLOCK)
 
 
-def compact_planes(packed: "np.ndarray", bw: "np.ndarray") -> "np.ndarray":
+def compact_planes(packed: np.ndarray, bw: np.ndarray) -> np.ndarray:
     """Host-side compaction of a fixed-stride packed buffer: keep only the
-    ``bw[b]`` live planes of each block. (nb, 32, 4) uint32 + (nb,) ->
-    (sum(bw), 4) uint32 rows, block-major then plane-major — the byte
-    stream the storage codec writes at flush (the docstring's 'compaction
-    to sum(bw_b) * 16 bytes happens at flush (host side)')."""
-    import numpy as np
-    packed = np.asarray(packed, np.uint32)
+    ``bw[b]`` live planes of each block. (nb, 32, 4) (or the device
+    layout's (nb, 128)) uint32 + (nb,) -> (sum(bw), 4) uint32 rows,
+    block-major then plane-major — the byte stream the storage codec
+    writes at flush (the docstring's 'compaction to sum(bw_b) * 16 bytes
+    happens at flush (host side)')."""
+    packed = np.asarray(packed, np.uint32).reshape(-1, 32, WORDS_PER_PLANE)
     bw = np.asarray(bw, np.int64)
     mask = np.arange(32)[None, :] < bw[:, None]
     return packed[mask]
 
 
-def expand_planes(rows: "np.ndarray", bw: "np.ndarray") -> "np.ndarray":
+def compact_words(packed: np.ndarray, bw: np.ndarray):
+    """The COMPACT index layout of a packed buffer: ``(words, coff)``.
+    ``words`` (M, 128) uint32 holds the compacted plane rows as one flat
+    word stream cut into 128-word rows, zero-padded so that the two rows
+    starting at any block's first word are in bounds (a 32-plane block
+    spans at most two); ``coff`` (nb,) int32 is each block's first plane
+    row, so its words start at flat offset ``4 * coff``."""
+    flat = compact_planes(packed, bw).reshape(-1)
+    words = np.zeros((flat.size // BLOCK + 2) * BLOCK, np.uint32)
+    words[:flat.size] = flat
+    bw = np.asarray(bw, np.int64)
+    return words.reshape(-1, BLOCK), (np.cumsum(bw) - bw).astype(np.int32)
+
+
+def expand_planes(rows: np.ndarray, bw: np.ndarray) -> np.ndarray:
     """Inverse of ``compact_planes``: scatter the compacted (sum(bw), 4)
     rows back into the fixed-stride (nb, 32, 4) buffer ``unpack_fast``
     consumes; dead planes (>= bw) are zero, as the pack contract requires."""
-    import numpy as np
     rows = np.asarray(rows, np.uint32).reshape(-1, WORDS_PER_PLANE)
     bw = np.asarray(bw, np.int64)
     full = np.zeros((len(bw), 32, WORDS_PER_PLANE), np.uint32)

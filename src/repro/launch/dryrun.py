@@ -26,6 +26,8 @@ import traceback
 from pathlib import Path
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
+# the chip the production meshes model (its device_kind keys the peaks)
+TARGET_KIND = "TPU v5 lite"
 
 
 def _build_lm(entry, shape, mesh):
@@ -248,7 +250,8 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     if entry.family == "lm":
         model_flops = RL.lm_model_flops(entry.config, shape)
         mem_analytic = RL.lm_memory_bytes(entry.config, shape, n_chips)
-    terms = RL.roofline_terms(cost, coll, n_chips, model_flops, mem_analytic)
+    terms = RL.roofline_terms(cost, coll, n_chips, model_flops, mem_analytic,
+                              device_kind=TARGET_KIND)
 
     return {
         "arch": arch_id, "shape": shape_name,

@@ -1,8 +1,10 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-  compute term    = HLO_FLOPs / (chips x 197 TFLOP/s bf16)
-  memory term     = HLO_bytes / (chips x 819 GB/s)
-  collective term = collective_bytes / (chips x 50 GB/s per ICI link)
+  compute term    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+  memory term     = HLO_bytes / (chips x peak HBM bytes/s)
+  collective term = collective_bytes / (chips x ICI link bytes/s)
+
+with the peaks of the target chip's ``device_kind`` (``launch/mesh.py``).
 
 HLO_FLOPs / bytes come from ``compiled.cost_analysis()`` (the partitioned
 SPMD module -> per-device numbers; we multiply back to global).
@@ -14,7 +16,7 @@ from __future__ import annotations
 
 import re
 
-from repro.launch.mesh import HBM_BW, ICI_LINK_BW, PEAK_BF16_FLOPS
+from repro.launch.mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -61,8 +63,10 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def roofline_terms(cost: dict, coll: dict, n_chips: int,
                    model_flops: float = 0.0,
-                   memory_bytes_analytic: float = 0.0) -> dict:
-    """cost: per-device cost_analysis dict. Terms are in SECONDS.
+                   memory_bytes_analytic: float = 0.0, *,
+                   device_kind: str) -> dict:
+    """cost: per-device cost_analysis dict. Terms are in SECONDS, against
+    the peaks of one ``device_kind`` chip.
 
     Two memory terms are reported: ``memory_s`` from HLO 'bytes accessed'
     (an UNFUSED upper bound — the CPU-backend HLO counts every
@@ -73,10 +77,11 @@ def roofline_terms(cost: dict, coll: dict, n_chips: int,
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
     coll_dev = float(coll["total_bytes"])
-    compute_s = flops_dev / PEAK_BF16_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    memory_analytic_s = memory_bytes_analytic / HBM_BW
-    collective_s = coll_dev / ICI_LINK_BW
+    peaks = chip_peaks(device_kind)
+    compute_s = flops_dev / peaks["bf16_flops"]
+    memory_s = bytes_dev / peaks["hbm_bw"]
+    memory_analytic_s = memory_bytes_analytic / peaks["hbm_bw"]
+    collective_s = coll_dev / peaks["ici_link_bw"]
     mem_for_bound = memory_analytic_s if memory_bytes_analytic else memory_s
     terms = {"compute": compute_s, "memory": mem_for_bound,
              "collective": collective_s}

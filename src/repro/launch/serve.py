@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as TF
 from repro.models.transformer import MeshInfo
 
@@ -207,6 +208,7 @@ def main(argv=None):
                          "commit, recover from disk, then serve (resumes "
                          "an existing index at its last commit point)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.mode == "retrieval":
         return serve_retrieval(args)

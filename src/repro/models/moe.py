@@ -187,7 +187,7 @@ def moe_ffn_shard_map(params, x, cfg, compute_dtype, mi):
     """EP via shard_map: local dispatch, psum combine (§Perf iteration 4)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
 
     B, S, d = x.shape
     dp = mi.dp()

@@ -25,10 +25,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 from repro.models import layers as L
 from repro.models.moe import moe_ffn, moe_init

@@ -23,7 +23,7 @@ single ``IndexSearcher`` over the union corpus:
     batch is skipped without being contacted at all.
 
 The final merge runs either on host or as an SPMD region over a mesh
-axis via the ``distributed/compat`` shard_map shim (each device holds
+axis via ``jax.shard_map`` (each device holds
 its shards' partials, all-gathers, and reduces to the replicated global
 top-k) — the same collective shape a TPU-resident fleet would use.
 
@@ -46,11 +46,10 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.query import PruneStats
-from repro.distributed.compat import shard_map
 
 
 @dataclass(frozen=True)
@@ -135,7 +134,7 @@ def merge_topk_sharded(vals, ids, k: int, mesh=None, axis: str = "shard"):
     """Global top-k from stacked per-shard partials ``(S, B, k)``.
 
     Host path: one transpose + top_k. Mesh path: an SPMD region through
-    the compat shard_map shim — each device holds its S/n shards'
+    ``jax.shard_map`` — each device holds its S/n shards'
     partials, all-gathers along ``axis``, and every device reduces to
     the same replicated global top-k (S must divide by the axis size).
     Both paths return ``(vals (B, k), ids (B, k))`` and are asserted

@@ -15,6 +15,14 @@ media profile) and never writes anything but replicated bytes. The only
 channel between them is the filesystem the manifests ship over — the
 command pipe carries queries and control, never index data.
 
+Replica processes are HOST processes: each child runs JAX on
+``REPLICA_PLATFORM`` (the CPU), set explicitly before its first JAX
+import so that no child takes the accelerator the parent or the writer
+holds. The child reports the platform it really got
+(``jax.devices()[0].platform``) in its state, readable as
+``RemoteReplica.platform``; a fleet that must serve from the chip runs
+its replicas in-process, one device each.
+
 ``epoch``/``healthy``/``missing_docs`` are cached parent-side and
 re-read after every state-changing call (sync/quarantine/repair), so the
 fleet's hot routing path costs no IPC beyond the search itself.
@@ -26,13 +34,15 @@ import os
 
 import numpy as np
 
+REPLICA_PLATFORM = "cpu"
+
 
 def replica_main(conn, replica_id: str, local_path: str, source_path: str,
                  peer_paths=(), prune: bool = True) -> None:
-    """Child-process entry: serve one replica until ``stop``."""
-    # searcher replicas are CPU processes; never let a child grab the
-    # accelerator the parent (or the writer) may be using
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    """Child-process entry: serve one replica on ``REPLICA_PLATFORM``
+    until ``stop``."""
+    os.environ["JAX_PLATFORMS"] = REPLICA_PLATFORM
+    import jax
     from repro.replication.syncer import ReplicaSyncer
     from repro.storage.directory import FSDirectory
     syncer = ReplicaSyncer(
@@ -42,7 +52,8 @@ def replica_main(conn, replica_id: str, local_path: str, source_path: str,
 
     def state():
         return {"epoch": syncer.epoch, "healthy": syncer.healthy,
-                "missing_docs": syncer.missing_docs, "gen": syncer.gen}
+                "missing_docs": syncer.missing_docs, "gen": syncer.gen,
+                "platform": jax.devices()[0].platform}
 
     while True:
         try:
@@ -103,7 +114,7 @@ class RemoteReplica:
         self._proc = None
         self._conn = None
         self._state = {"epoch": 0, "healthy": True,
-                       "missing_docs": 0, "gen": 0}
+                       "missing_docs": 0, "gen": 0, "platform": None}
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "RemoteReplica":
@@ -155,6 +166,12 @@ class RemoteReplica:
     @property
     def gen(self) -> int:
         return self._state["gen"]
+
+    @property
+    def platform(self):
+        """The JAX platform the child serves on (None before its first
+        state-changing call)."""
+        return self._state["platform"]
 
     def sync_once(self):
         out, self._state = self._call("sync")

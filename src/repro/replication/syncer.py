@@ -70,19 +70,24 @@ class ReplicaSyncer:
     local copy rots. All three are plain ``Directory`` objects, so a
     "remote" fetch is a read through whatever media profile models the
     transport — the same modeling stance as the rest of the repo.
+
+    ``device`` (a ``jax.Device``) pins the replica's readers to one
+    device, as a fleet serving one shard per chip needs: every refresh
+    builds there, background polls and repairs included. None builds on
+    JAX's default device of the refreshing thread.
     """
 
     def __init__(self, directory: Directory, source: Directory,
                  peers=(), replica_id: str = None, reader_cache=None,
                  prune: bool = True, k1: float = 0.9, b: float = 0.4,
-                 publisher=None):
+                 publisher=None, device=None):
         self.directory = directory
         self.source = source
         self.peers = list(peers)
         self.replica_id = replica_id or f"replica-{id(self) & 0xffff:04x}"
         self.publisher = publisher
         self.cache = reader_cache if reader_cache is not None \
-            else ReaderCache(k1=k1, b=b, prune=prune)
+            else ReaderCache(k1=k1, b=b, prune=prune, device=device)
         if publisher is not None:
             publisher.register(self.replica_id)
         self.gen = 0

@@ -59,7 +59,6 @@ from __future__ import annotations
 import struct
 import zlib
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.segments import Segment
@@ -163,11 +162,9 @@ def _enc_pfor(arr: np.ndarray) -> bytes:
         return head
     padded = np.zeros(nb * pack_ref.BLOCK, np.uint32)
     padded[:n] = arr.astype(np.uint32)
-    packed, bw = pack_ref.pack_fast(
-        jnp.asarray(padded.reshape(nb, pack_ref.BLOCK)))
-    packed_np = np.asarray(packed, np.uint32)
-    bw_np = np.asarray(bw, np.int64)
-    rows = pack_ref.compact_planes(packed_np, bw_np)
+    packed, bw = pack_ref.pack_fast(padded.reshape(nb, pack_ref.BLOCK))
+    bw_np = bw.astype(np.int64)
+    rows = pack_ref.compact_planes(packed, bw_np)
     return (head + bw_np.astype(np.uint8).tobytes()
             + rows.astype("<u4").tobytes())
 
@@ -281,7 +278,7 @@ def _dec_pfor(buf: bytes, off: int) -> tuple[np.ndarray, int]:
         raise CorruptSegment("pfor stream truncated")
     rows = np.frombuffer(buf[off:end], "<u4")
     full = pack_ref.expand_planes(rows, bw)
-    vals = np.asarray(pack_ref.unpack_fast(jnp.asarray(full), bw))
+    vals = pack_ref.unpack_fast(full, bw)
     if n > nb * pack_ref.BLOCK:
         raise CorruptSegment("stream count exceeds packed blocks")
     return vals.reshape(-1)[:n].astype(np.int64), end

@@ -19,6 +19,11 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 900):
     env_code = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={n}'\n"
+        # Auto axis types: the models constrain shardings with
+        # with_sharding_constraint, which Explicit axes (the default of
+        # jax.make_mesh) refuse
+        "from jax.sharding import AxisType\n"
+        "AUTO1, AUTO2 = (AxisType.Auto,), (AxisType.Auto,) * 2\n"
     )
     r = subprocess.run(
         [sys.executable, "-c", env_code + textwrap.dedent(code)],
@@ -35,15 +40,12 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 900):
 
 
 def test_spmd_regions_import_the_compat_shim():
-    """Every SPMD region must get shard_map from the version-stable shim
-    in ``repro/distributed/compat.py`` — importing it straight from
-    ``jax.experimental`` reintroduces the cross-version API drift the
-    shim exists to absorb. Lint, not runtime: grep the source tree."""
-    shim = REPO / "src" / "repro" / "distributed" / "compat.py"
+    """Every SPMD region takes ``shard_map`` from one stable place, the
+    installed JAX's top-level ``jax.shard_map`` — never the retired
+    ``jax.experimental.shard_map`` module. Lint, not runtime: grep the
+    source tree."""
     bad = []
     for path in sorted((REPO / "src").rglob("*.py")):
-        if path == shim:
-            continue
         for ln, line in enumerate(path.read_text().splitlines(), 1):
             code = line.split("#", 1)[0]
             if "jax.experimental.shard_map" in code or \
@@ -51,17 +53,16 @@ def test_spmd_regions_import_the_compat_shim():
                      and "jax.experimental" in code):
                 bad.append(f"{path.relative_to(REPO)}:{ln}: {line.strip()}")
     assert not bad, \
-        "import shard_map from repro.distributed.compat, not " \
-        "jax.experimental:\n" + "\n".join(bad)
+        "use jax.shard_map, not jax.experimental:\n" + "\n".join(bad)
 
 
 def test_shuffle_conservation_and_ownership():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.compat import shard_map
+        from jax import shard_map
         from repro.core.shuffle import invert_and_shuffle
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",), axis_types=AUTO1)
         D_per, L, V = 8, 24, 71
         rng = np.random.default_rng(2)
         tokens = rng.integers(0, V, size=(64, L)).astype(np.int32)
@@ -102,7 +103,7 @@ def test_cp_attention_matches_single_device():
         batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1),
                  "mask": jnp.ones((B, S))}
         l_single, _ = forward_train(params, batch, cfg, MeshInfo())
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         mi = MeshInfo(mesh=mesh, dp_axes=("data",))
         with mesh:
             l_dist, _ = jax.jit(
@@ -128,7 +129,7 @@ def test_distributed_grads_match_single_device():
         batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1),
                  "mask": jnp.ones((B, S))}
         g1 = jax.grad(lambda p: forward_train(p, batch, cfg, MeshInfo())[0])(params)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         mi = MeshInfo(mesh=mesh, dp_axes=("data",))
         with mesh:
             g2 = jax.jit(jax.grad(
@@ -149,7 +150,7 @@ def test_distributed_index_step_compiles_and_runs():
         from repro.configs.registry import get_arch
         from repro.core.indexer import make_index_step
         cfg = get_arch("lucene-envelope").smoke
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         step = make_index_step(cfg, mesh, doc_len=cfg.doc_len)
         rng = np.random.default_rng(0)
         tokens = rng.integers(0, 1 << cfg.vocab_bits,
@@ -172,9 +173,9 @@ def test_packed2_shuffle_parity():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.compat import shard_map
+        from jax import shard_map
         from repro.core.shuffle import invert_and_shuffle
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",), axis_types=AUTO1)
         D_per, L, V = 16, 32, 97
         rng = np.random.default_rng(2)
         tokens = rng.integers(0, V, size=(128, L)).astype(np.int32)
@@ -221,7 +222,7 @@ def test_shard_map_moe_parity():
         batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1),
                  "mask": jnp.ones((B, S))}
         l0, _ = forward_train(params, batch, cfg_p, MeshInfo())
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         mi = MeshInfo(mesh=mesh, dp_axes=("data",))
         with mesh:
             l1, _ = jax.jit(lambda p, b: forward_train(p, b, cfg_p, mi))(params, batch)
@@ -247,7 +248,7 @@ def test_moe_dispatch_parity_across_mesh():
         batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, 1),
                  "mask": jnp.ones((B, S))}
         l1, _ = forward_train(params, batch, cfg, MeshInfo())
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         mi = MeshInfo(mesh=mesh, dp_axes=("data",))
         with mesh:
             l2, _ = jax.jit(lambda p, b: forward_train(p, b, cfg, mi))(params, batch)

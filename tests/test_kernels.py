@@ -19,22 +19,24 @@ def test_pack_kernel_matches_ref(nb, scale):
     d = jnp.asarray(rng.integers(0, scale, size=(nb, 128), dtype=np.int64)
                     .astype(np.uint32))
     p_ref, bw_ref = pref.pack_ref(d)
-    p_k, bw_k = pack_pallas(d, block_rows=min(64, nb))
-    np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_ref))
+    p_k, bw_k = pack_pallas(d, block_rows=min(64, nb), interpret=True)
+    np.testing.assert_array_equal(np.asarray(p_k).reshape(p_ref.shape),
+                                  np.asarray(p_ref))
     np.testing.assert_array_equal(np.asarray(bw_k), np.asarray(bw_ref))
-    u = unpack_pallas(p_k, bw_k, block_rows=min(64, nb))
+    u = unpack_pallas(p_k, bw_k, block_rows=min(64, nb), interpret=True)
     np.testing.assert_array_equal(np.asarray(u), np.asarray(d))
 
 
 def test_pack_edge_cases():
     zeros = jnp.zeros((128, 128), jnp.uint32)
-    p, bw = pack_pallas(zeros, block_rows=128)
+    p, bw = pack_pallas(zeros, block_rows=128, interpret=True)
     assert (np.asarray(bw) == 0).all()
     maxed = jnp.full((128, 128), 0xFFFFFFFF, jnp.uint32)
-    p, bw = pack_pallas(maxed, block_rows=128)
+    p, bw = pack_pallas(maxed, block_rows=128, interpret=True)
     assert (np.asarray(bw) == 32).all()
     np.testing.assert_array_equal(
-        np.asarray(unpack_pallas(p, bw, block_rows=128)), np.asarray(maxed))
+        np.asarray(unpack_pallas(p, bw, block_rows=128, interpret=True)),
+        np.asarray(maxed))
 
 
 @settings(max_examples=30, deadline=None)
@@ -55,11 +57,7 @@ def test_pack_roundtrip_property(vals, extra):
 def test_bm25_skip_kernel_over_compacted_survivors(nb):
     """The rewritten skip kernel: grid over a COMPACTED survivor array
     (power-of-two sizes, the shapes ``compact_survivors`` emits), fused
-    unpack + score, and the running per-lane top-partial carry
-    accumulated across grid steps — all vs the jnp oracle in interpret
-    mode."""
-    from repro.kernels.bm25_blockmax.ops import bm25_blocks_partials
-    from repro.kernels.bm25_blockmax.ref import lane_partials_ref
+    unpack + score — vs the jnp oracle in interpret mode."""
     rng = np.random.default_rng(nb + 1)
     deltas = rng.integers(0, 50, (nb, 128)).astype(np.uint32)
     deltas[:, 0] = 0
@@ -69,27 +67,19 @@ def test_bm25_skip_kernel_over_compacted_survivors(nb):
     first = jnp.asarray(rng.integers(0, 5000, nb).astype(np.int32))
     idf = jnp.asarray(rng.random(nb).astype(np.float32) * 4)
     act = jnp.asarray((rng.random(nb) < 0.8).astype(np.int32))
-    doc, tf_o, num, part = bm25_blocks_partials(pd, bwd, first, pt, bwt,
-                                                idf, act)
+    doc, tf_o, num = bm25_blocks_pallas(pd, bwd, first, pt, bwt, idf, act,
+                                        interpret=True)
     ref = bm25_blocks_ref(pd, bwd, first, pt, bwt, idf, act)
     for got, want in zip((doc, tf_o, num), ref):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6)
-    # the carry is the per-lane max partial bound over every grid step
-    want_part = lane_partials_ref(ref[1], ref[2])
-    assert part.shape == (1, 128)
-    np.testing.assert_allclose(np.asarray(part), np.asarray(want_part),
-                               rtol=1e-6)
 
 
 def _compact_layout(packed, bw):
-    """(cplanes tail-padded with 32 zero rows, exclusive-cumsum offsets)
-    — the layout ``build_block_index(compact=True)`` stores."""
-    bw_np = np.asarray(bw, np.int64)
-    rows = pref.compact_planes(np.asarray(packed), bw_np)
-    pad = np.zeros((32, pref.WORDS_PER_PLANE), np.uint32)
-    coff = (np.cumsum(bw_np) - bw_np).astype(np.int32)
-    return jnp.asarray(np.vstack([rows, pad])), jnp.asarray(coff)
+    """(compact words, exclusive-cumsum plane offsets) — the layout
+    ``build_block_index(compact=True)`` stores."""
+    words, coff = pref.compact_words(np.asarray(packed), np.asarray(bw))
+    return jnp.asarray(words), jnp.asarray(coff)
 
 
 def test_compact_planes_roundtrip():
@@ -205,7 +195,7 @@ def test_bm25_kernel_matches_ref(nb):
     act = jnp.asarray((rng.random(nb) < 0.7).astype(np.int32))
     ref = bm25_blocks_ref(pd, bwd, first, pt, bwt, idf, act)
     ker = bm25_blocks_pallas(pd, bwd, first, pt, bwt, idf, act,
-                             block_rows=min(4, nb))
+                             block_rows=min(4, nb), interpret=True)
     for r, k in zip(ref, ker):
         np.testing.assert_allclose(np.asarray(k), np.asarray(r), rtol=1e-6)
     # semantic check: docids are prefix sums of deltas where active

@@ -27,7 +27,8 @@ def _mk(B, Sq, Skv, H, KVH, D, dtype, seed=0):
 def test_flash_matches_ref(shape, dtype):
     B, Sq, Skv, H, KVH, D = shape
     q, k, v = _mk(B, Sq, Skv, H, KVH, D, dtype)
-    out_k = flash_attention(q, k, v, causal=True, block_q=64, block_kv=64)
+    out_k = flash_attention(q, k, v, causal=True, block_q=64, block_kv=64,
+                            interpret=True)
     out_r = attention_ref(q, k, v, causal=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out_k, np.float32),
@@ -40,7 +41,8 @@ def test_flash_matches_ref(shape, dtype):
 def test_flash_window_softcap(window, softcap):
     q, k, v = _mk(1, 128, 128, 4, 2, 64, jnp.float32, seed=3)
     out_k = flash_attention(q, k, v, causal=True, window=window,
-                            softcap=softcap, block_q=32, block_kv=32)
+                            softcap=softcap, block_q=32, block_kv=32,
+                            interpret=True)
     out_r = attention_ref(q, k, v, causal=True, window=window,
                           softcap=softcap)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
@@ -49,7 +51,8 @@ def test_flash_window_softcap(window, softcap):
 
 def test_flash_noncausal():
     q, k, v = _mk(1, 64, 96, 2, 2, 64, jnp.float32, seed=5)
-    out_k = flash_attention(q, k, v, causal=False, block_q=32, block_kv=32)
+    out_k = flash_attention(q, k, v, causal=False, block_q=32, block_kv=32,
+                            interpret=True)
     out_r = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=2e-5, atol=2e-5)
@@ -61,7 +64,8 @@ def test_flash_matches_model_attention():
     from repro.models.transformer import _blockwise_traced_window
     q, k, v = _mk(2, 128, 128, 4, 2, 64, jnp.float32, seed=7)
     out_k = flash_attention(q, k, v, causal=True, window=32,
-                            block_q=64, block_kv=64)
+                            block_q=64, block_kv=64,
+                            interpret=True)
     out_m = _blockwise_traced_window(q, k, v, jnp.int32(32), jnp.int32(0),
                                      softcap=0.0, block_q=64, block_kv=64)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_m),

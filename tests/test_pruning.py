@@ -69,9 +69,13 @@ def test_pruned_multisegment_bit_identical_to_forcemerged(seed, n_segs):
         v_s, i_s = searcher.search(_q, k)
         v_s, i_s = np.asarray(v_s), np.asarray(i_s)
         assert np.array_equal(v_s, v_e), (v_s, v_e)
-        local = np.searchsorted(live_ids, i_s)
-        for val, li in zip(v_s, local):
-            assert truth[int(li)] == val
+        for val, doc in zip(v_s, i_s):
+            if doc < 0:   # no-hit slot: fewer scoring live docs than k
+                assert val == 0
+                continue
+            li = int(np.searchsorted(live_ids, doc))
+            assert li < live_ids.size and live_ids[li] == doc  # live doc
+            assert truth[li] == val
     # batched path agrees with the single path
     qb = np.stack([_q, _q])
     vb, ib = pruned.search_batched(qb, k)

@@ -251,6 +251,60 @@ def test_merge_topk_sharded_mesh_matches_host():
     assert "MESH-TOPK-OK" in out
 
 
+def test_replica_readers_stay_on_their_device():
+    """A replica given ``device=`` keeps every reader array on that
+    device through a later sync that runs on another thread (as the
+    background poll does), where JAX's thread-local default device is
+    the process default — both the new segment's build and the delete
+    generation's reopen."""
+    out = run_with_devices("""
+        import threading
+        import jax, numpy as np
+        from repro.configs.registry import get_arch
+        from repro.core.indexer import DistributedIndexer
+        from repro.core.searcher import _index_arrays
+        from repro.data.corpus import TINY, SyntheticCorpus
+        from repro.replication import ReplicaSyncer
+        from repro.storage import RAMDirectory
+        cfg = get_arch("lucene-envelope").smoke
+        corpus = SyntheticCorpus(TINY, doc_buffer_len=cfg.doc_len)
+        devs = jax.devices()
+
+        def placement(syncer):
+            arrs = []
+            for r in syncer.searcher.readers:
+                arrs += list(_index_arrays(r.index)) + [r.doc_map]
+                arrs += [] if r.live is None else [r.live]
+            return (set().union(*(a.devices() for a in arrs)),
+                    all(a.committed for a in arrs))
+
+        for si in (1, 3):
+            base = si * 1_000_000
+            ix = DistributedIndexer(cfg=cfg, target_dir=RAMDirectory(),
+                                    doc_base=base)
+            ix.index_batch(corpus.batch(2 * si, 16))
+            ix.commit()
+            syncer = ReplicaSyncer(RAMDirectory(), ix.target_dir,
+                                   device=devs[si])
+            assert syncer.sync_once() is not None
+            assert placement(syncer) == ({devs[si]}, True), placement(syncer)
+            ix.index_batch(corpus.batch(2 * si + 1, 16))
+            ix.delete(np.arange(base, base + 4))
+            ix.commit()
+            got = []
+            t = threading.Thread(target=lambda: got.append(syncer.sync_once()))
+            t.start()
+            t.join()
+            assert got and got[0] is not None
+            readers = syncer.searcher.readers
+            assert len(readers) >= 2 and any(r.live is not None
+                                             for r in readers)
+            assert placement(syncer) == ({devs[si]}, True), placement(syncer)
+        print("REPLICA-DEVICE-OK")
+    """, n=4)
+    assert "REPLICA-DEVICE-OK" in out
+
+
 # ---------------------------------------------------------------------------
 # quarantine-driven failover
 # ---------------------------------------------------------------------------
@@ -509,6 +563,7 @@ def test_remote_replica_processes_converge_and_heal(tmp_path):
         for r in reps:
             out = r.sync_once()
             assert out["gen"] == 1 and r.gen == 1 and r.healthy
+            assert r.platform == "cpu"     # host processes, said explicitly
         # convergence tracks EVERY commit
         ix.index_batch(CORPUS.batch(2, 16))
         ix.commit()

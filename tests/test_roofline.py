@@ -1,4 +1,6 @@
 """Roofline machinery unit tests: HLO collective parsing + term math."""
+import pytest
+
 from repro.launch import roofline as RL
 
 
@@ -34,14 +36,19 @@ def test_collective_bytes_parsing():
 def test_roofline_terms_math():
     cost = {"flops": 197e12, "bytes accessed": 819e9}
     coll = {"total_bytes": 50e9}
-    t = RL.roofline_terms(cost, coll, n_chips=256, model_flops=197e12 * 256)
+    t = RL.roofline_terms(cost, coll, n_chips=256, model_flops=197e12 * 256,
+                          device_kind="TPU v5 lite")
     assert abs(t["compute_s"] - 1.0) < 1e-9
     assert abs(t["memory_s"] - 1.0) < 1e-9
     assert abs(t["collective_s"] - 1.0) < 1e-9
     assert abs(t["useful_flops_ratio"] - 1.0) < 1e-9
     # analytic memory overrides dominance
-    t2 = RL.roofline_terms(cost, coll, 256, memory_bytes_analytic=819e9 * 10)
+    t2 = RL.roofline_terms(cost, coll, 256, memory_bytes_analytic=819e9 * 10,
+                           device_kind="TPU v5 lite")
     assert t2["dominant"] == "memory"
+    # a chip without published peaks is an error, not a default
+    with pytest.raises(KeyError):
+        RL.roofline_terms(cost, coll, 256, device_kind="TPU v99")
 
 
 def test_lm_model_flops_conventions():

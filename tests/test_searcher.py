@@ -83,29 +83,35 @@ def test_build_reordered_and_compact_bit_identical(live_index):
     assert cmp.compact and cmp.packed_docs is None
     for stream in ("docs", "tf"):
         bw = np.asarray(getattr(vec, f"bw_{stream}"), np.int64)
-        rows = np.asarray(getattr(cmp, f"cplanes_{stream}"))
+        words = np.asarray(getattr(cmp, f"cwords_{stream}")).reshape(-1)
         coff = np.asarray(getattr(cmp, f"coff_{stream}"))
-        assert rows.shape == (int(bw.sum()) + 32, pack_ref.WORDS_PER_PLANE)
+        n_words = int(bw.sum()) * pack_ref.WORDS_PER_PLANE
+        assert words.size == (n_words // 128 + 2) * 128
+        assert not words[n_words:].any()
         assert np.array_equal(coff, np.cumsum(bw) - bw)
-        back = pack_ref.expand_planes(rows[:-32], bw)
-        want = np.asarray(getattr(vec, f"packed_{stream}")) * (
-            np.arange(32)[None, :, None] < bw[:, None, None])
+        back = pack_ref.expand_planes(words[:n_words], bw)
+        want = np.asarray(getattr(vec, f"packed_{stream}")).reshape(
+            -1, 32, 4) * (np.arange(32)[None, :, None] < bw[:, None, None])
         assert np.array_equal(back, want)
 
 
 def test_pack_unpack_fast_match_reference():
     rng = np.random.default_rng(7)
     for hi in (1, 1000, 2 ** 20, 2 ** 32 - 1):
-        d = jnp.asarray(rng.integers(0, hi + 1, (32, 128),
-                                     dtype=np.uint64).astype(np.uint32))
-        p_r, bw_r = pack_ref.pack_ref(d)
-        p_f, bw_f = pack_ref.pack_fast(d)
-        assert (np.asarray(p_r) == np.asarray(p_f)).all()
-        assert (np.asarray(bw_r) == np.asarray(bw_f)).all()
+        d = rng.integers(0, hi + 1, (32, 128),
+                         dtype=np.uint64).astype(np.uint32)
+        d[0] = 0                                  # a bw-0 block
+        p_r, bw_r = pack_ref.pack_ref(jnp.asarray(d))
+        p_f, bw_f = pack_ref.pack_fast(d)         # the codec's host pack
+        assert isinstance(p_f, np.ndarray) and isinstance(bw_f, np.ndarray)
+        assert (np.asarray(p_r) == p_f).all()
+        assert (np.asarray(bw_r) == bw_f).all()
         u_r = pack_ref.unpack_ref(p_r, bw_r)
-        u_f = pack_ref.unpack_fast(p_r, bw_r)
+        u_f = pack_ref.unpack_fast(p_r, bw_r)     # jnp: the reference path
+        u_h = pack_ref.unpack_fast(p_f, bw_f)     # numpy: the codec's decode
+        assert isinstance(u_h, np.ndarray)
         assert (np.asarray(u_r) == np.asarray(u_f)).all()
-        assert (np.asarray(u_f) == np.asarray(d)).all()
+        assert (np.asarray(u_f) == d).all() and (u_h == d).all()
 
 
 def test_multisegment_equals_forcemerged(live_index):
