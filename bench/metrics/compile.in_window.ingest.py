@@ -1,0 +1,7 @@
+"""Programs compiled, or loaded from the persistent cache, inside the
+measured ingest window (JAX's monitoring events); set-up should leave
+none."""
+
+
+def read(ctx):
+    return ctx.counters.get("programs_in_window")
