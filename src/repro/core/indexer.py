@@ -52,6 +52,7 @@ from repro.core.searcher import IndexSearcher, ReaderCache
 from repro.core.segments import Segment, segment_from_run
 from repro.core.shuffle import invert_and_shuffle
 from repro.kernels.postings_pack import ref as pack_ref
+from repro.spans import span
 
 
 def _flat_device_index(mesh_axis_names, mesh_shape):
@@ -117,8 +118,7 @@ class IndexStats:
     docs: int = 0
     tokens: int = 0
     read_bytes: int = 0
-    flushed_bytes: int = 0
-    shuffle_bytes: int = 0
+    flushes: int = 0
     wall_s: float = 0.0
     refreshes: int = 0
     last_refresh_s: float = 0.0
@@ -479,35 +479,46 @@ class DistributedIndexer:
                 # live segment set, so the whole log is commit-covered
                 self._wal_covered = self._wal.next_seq - 1
             return None
-        t0 = time.time()
+        t0 = time.perf_counter()
         tokens = self._flush_policy.take()
         D = tokens.shape[0]
         base = self._next_doc
         self._next_doc += D
-        run = self._jit_invert(jnp.asarray(tokens), base)
-        run_np = {k: np.asarray(getattr(run, k)) for k in run._fields}
-        seg = segment_from_run(run_np, np.arange(base, base + D),
-                               run_np["doc_len"])
-        if getattr(self.cfg, "reorder_on_flush", False):
-            # BP doc-id reassignment at flush time: the freshest (and most
-            # queried, under NRT churn) segments get impact-homogeneous
-            # blocks too, not just merge outputs. Scores stay bit-identical
-            # (the permutation only relabels local slots).
-            perm = reassign_doc_ids(seg)
-            if perm is not None:
-                seg = replace(seg, reorder=perm)
-        self.merger.add_flush(seg)
-        # Lucene's BufferedUpdates contract: deletes land WITH the flush
-        # (after it, so deletes targeting docs in this very buffer hit
-        # the segment they just became), then the buffer drains
-        self._apply_deletes_locked(drain=True)
+        self.stats.flushes += 1
+        with span("indexer.flush", flush=self.stats.flushes, docs=D):
+            # the host waits at the end of each device stage, so each
+            # stage's span holds its own work
+            with span("flush.to_device"):
+                toks = jax.block_until_ready(jnp.asarray(tokens))
+            with span("flush.invert"):
+                run = jax.block_until_ready(self._jit_invert(toks, base))
+            with span("flush.to_host"):
+                run_np = {k: np.asarray(getattr(run, k))
+                          for k in run._fields}
+            with span("flush.segment"):
+                seg = segment_from_run(run_np, np.arange(base, base + D),
+                                       run_np["doc_len"])
+                if getattr(self.cfg, "reorder_on_flush", False):
+                    # BP doc-id reassignment at flush time: the freshest
+                    # (and most queried, under NRT churn) segments get
+                    # impact-homogeneous blocks too, not just merge
+                    # outputs. Scores stay bit-identical (the permutation
+                    # only relabels local slots).
+                    perm = reassign_doc_ids(seg)
+                    if perm is not None:
+                        seg = replace(seg, reorder=perm)
+            self.merger.add_flush(seg)
+            # Lucene's BufferedUpdates contract: deletes land WITH the
+            # flush (after it, so deletes targeting docs in this very
+            # buffer hit the segment they just became), then the buffer
+            # drains
+            self._apply_deletes_locked(drain=True)
         if self._wal is not None:
             # every record appended before this flush (same lock) is now
             # represented in flushed segments + applied deletes: the next
             # successful commit makes them durable and may truncate
             self._wal_covered = self._wal.next_seq - 1
-        self.stats.flushed_bytes += seg.total_bytes()
-        self.stats.wall_s += time.time() - t0
+        self.stats.wall_s += time.perf_counter() - t0
         return seg
 
     def index_spooled(self, directory=None) -> int:
@@ -530,18 +541,21 @@ class DistributedIndexer:
         referenced by the manifest) and delete superseded files. Returns
         the new commit generation."""
         assert self.store is not None, "commit() requires target_dir"
-        with self._flush_lock:
-            if flush:
-                self._flush_locked()
-            else:
-                self._apply_deletes_locked(drain=False)
-            covered = self._wal_covered
-        gen = self.store.commit(self.merger.live_segments())
-        if self._wal is not None and covered >= 0:
-            # only once the commit is durable are its records disposable
-            self._wal.truncate_upto(covered)
-        if self.publisher is not None:
-            self.publisher.on_commit(gen)   # shippable to replicas now
+        with span("indexer.commit") as sp:
+            with self._flush_lock:
+                if flush:
+                    self._flush_locked()
+                else:
+                    self._apply_deletes_locked(drain=False)
+                covered = self._wal_covered
+            gen = self.store.commit(self.merger.live_segments())
+            sp.set_metadata(gen=gen)
+            if self._wal is not None and covered >= 0:
+                # only once the commit is durable are its records
+                # disposable
+                self._wal.truncate_upto(covered)
+            if self.publisher is not None:
+                self.publisher.on_commit(gen)   # shippable to replicas now
         return gen
 
     def finalize(self) -> Segment:
@@ -613,7 +627,7 @@ class DistributedIndexer:
                 self._flush_locked()
             else:
                 self._apply_deletes_locked(drain=False)
-        t0 = time.time()
+        t0 = time.perf_counter()
         recovery = None
         if self.store is not None and self.store.quarantined:
             from repro.storage.commit import RecoveryInfo
@@ -622,7 +636,7 @@ class DistributedIndexer:
         searcher = self.reader_cache.refresh(self.merger.live_segments(),
                                              recovery=recovery)
         self.stats.refreshes += 1
-        self.stats.last_refresh_s = time.time() - t0
+        self.stats.last_refresh_s = time.perf_counter() - t0
         self.searcher = searcher   # the (atomic) NRT swap
         # serving hooks: swap attached schedulers to the new snapshot —
         # its generation keys result caches, so a content change here IS

@@ -41,6 +41,7 @@ from dataclasses import dataclass, field, replace
 import numpy as np
 
 from repro.core.segments import Segment, fresh_seg_id, live_posting_stats
+from repro.spans import span
 
 
 def _bump_single(seg: Segment) -> Segment:
@@ -439,7 +440,9 @@ class MergeDriver:
         """Account a freshly flushed segment. With a scheduler attached
         this only notifies the background pool (the caller — the ingest
         thread — never merges); without one it cascades synchronously."""
-        sz = seg.total_bytes()  # memoized: the O(P) pass stays off the lock
+        with span("flush.account"):
+            # memoized: the O(P) pass stays off the lock
+            sz = seg.total_bytes()
         if self.store is not None:
             # durable write-path: the segment's bytes hit the target medium
             # before the segment is searchable, so a commit taken at any

@@ -55,6 +55,7 @@ import numpy as np
 from repro.kernels.bm25_blockmax.ops import (bm25_blocks, bm25_blocks_compact,
                                              bm25_blocks_midgrid)
 from repro.kernels.postings_pack import ops as pack_ops
+from repro.spans import span
 
 BLOCK = 128
 # phase-1 budget: blocks scored to establish theta. One 128-lane block
@@ -605,6 +606,53 @@ def _bmw_overlap_others(ub3, f3, l3, sentinel: int):
     return overlap
 
 
+def _bound_test(ub, in_term, bf, bl, theta, Q: int, bmw: bool):
+    """Phase 2 of ``pruned_eval`` on host metadata: the blocks that
+    survive the bound test at ``theta``. ``ub``/``in_term`` are (B, Q*MB);
+    ``bf``/``bl`` the blocks' first and last doc ids (read only by
+    ``bmw``). Returns ``(surv (B, Q*MB) bool, bound (B, Q*MB),
+    terms eliminated)``."""
+    B = ub.shape[0]
+    ub3 = ub.reshape(B, Q, -1)
+    MB = ub3.shape[2]
+    term_best = ub3.max(axis=2)                            # (B, Q)
+    n_elim = 0
+    if bmw:
+        # doc-range-overlap "others" bound. Pad entries get a sentinel
+        # extent past every real doc id: rows stay sorted (in_term is a
+        # prefix mask, pads trail) and sentinel ranges only overlap other
+        # sentinel ranges, whose UB is 0.
+        in3 = in_term.reshape(B, Q, MB)
+        sentinel = int(max(bl.max(initial=0), bf.max(initial=0)) + 1)
+        f3 = np.where(in3, bf.reshape(B, Q, MB), sentinel)
+        l3 = np.where(in3, bl.reshape(B, Q, MB), sentinel)
+        bound3 = ub3 + _bmw_overlap_others(ub3, f3, l3, sentinel)
+        base = in3 & (bound3 > theta[:, None, None])
+        # non-essential list elimination: sort terms by ascending best
+        # contribution; the maximal prefix whose cumulative sum cannot
+        # beat theta is non-essential. A winner (true score > theta) must
+        # carry >= 1 essential term, so non-essential terms generate no
+        # candidates of their own — their blocks are kept only when they
+        # range-overlap a SURVIVING essential block (those are the only
+        # places a winner's remaining contributions can live).
+        order = np.argsort(term_best, axis=1, kind="stable")
+        csum = np.cumsum(np.take_along_axis(term_best, order, 1), axis=1)
+        ness = np.zeros((B, Q), bool)
+        np.put_along_axis(ness, order, csum <= theta[:, None], 1)
+        has_blocks = in3.any(axis=2)
+        n_elim = int((ness & has_blocks).sum())
+        if ness.any():
+            ess_surv = (base & ~ness[:, :, None]).astype(np.float64)
+            touches = _bmw_overlap_others(ess_surv, f3, l3, sentinel) > 0
+            base = np.where(ness[:, :, None], base & touches, base)
+        return base.reshape(B, -1), bound3.reshape(B, -1), n_elim
+    # term-level MaxScore baseline: every other term helps with its
+    # global best block, wherever that block lives in doc space
+    others = term_best.sum(axis=1, keepdims=True) - term_best
+    bound = (ub3 + others[:, :, None]).reshape(B, -1)
+    return in_term & (bound > theta[:, None]), bound, n_elim
+
+
 def pruned_eval(meta, scorer_for, q2d, idf2d, k: int, theta0=None,
                 n_phase1: int = PHASE1_BLOCKS, bmw: bool = True,
                 scorer_mid_for=None):
@@ -660,14 +708,19 @@ def pruned_eval(meta, scorer_for, q2d, idf2d, k: int, theta0=None,
     true score, so any value the final top-k surfaces is exact (ties at
     theta are covered by the unconditionally-kept phase-1 probes / the
     ``theta0`` securing contract).
-    Returns ``(vals, ids, PruneStats)``.
+    Returns ``(vals, ids, PruneStats)``, ``vals``/``ids`` fetched to the
+    host.
     """
-    ub_d, in_term_d, bidx_d, idf_pb_d, bf_d, bl_d = meta(q2d, idf2d)
     B = q2d.shape[0]
-    ub = np.asarray(ub_d, np.float64).reshape(B, -1)
-    in_term = np.asarray(in_term_d).reshape(B, -1)
-    bidx = np.asarray(bidx_d).reshape(B, -1)
-    idf_pb = np.asarray(idf_pb_d).reshape(B, -1)
+    with span("prune.meta"):
+        ub_d, in_term_d, bidx_d, idf_pb_d, bf_d, bl_d = meta(q2d, idf2d)
+        ub = np.asarray(ub_d, np.float64).reshape(B, -1)
+        in_term = np.asarray(in_term_d).reshape(B, -1)
+        bidx = np.asarray(bidx_d).reshape(B, -1)
+        idf_pb = np.asarray(idf_pb_d).reshape(B, -1)
+        bf = bl = None
+        if bmw:
+            bf, bl = np.asarray(bf_d, np.int64), np.asarray(bl_d, np.int64)
     n_cand = ub.shape[1]
     t0 = (np.zeros(B, np.float64) if theta0 is None
           else np.broadcast_to(np.asarray(theta0, np.float64),
@@ -682,21 +735,22 @@ def pruned_eval(meta, scorer_for, q2d, idf2d, k: int, theta0=None,
     probed = 0
     top = None
     if not bool(np.all(t0 > 0)):
-        P1 = min(n_phase1, n_cand)
-        ubm = np.where(in_term, ub, -1.0)
-        top = np.argpartition(-ubm, P1 - 1, axis=1)[:, :P1]
-        p1_act = np.take_along_axis(in_term, top, 1)
-        probed = _pow2ceil(B * P1)
-        p1_ids = np.zeros(probed, np.int32)
-        p1_idf = np.zeros(probed, np.float32)
-        p1_actf = np.zeros(probed, bool)
-        p1_row = np.zeros(probed, np.int32)
-        p1_ids[:B * P1] = np.take_along_axis(bidx, top, 1).reshape(-1)
-        p1_idf[:B * P1] = np.take_along_axis(idf_pb, top, 1).reshape(-1)
-        p1_actf[:B * P1] = p1_act.reshape(-1)
-        p1_row[:B * P1] = np.repeat(np.arange(B, dtype=np.int32), P1)
-        vals1, _ = scorer_for(probed)(p1_ids, p1_idf, p1_actf, p1_row)
-        theta = np.maximum(np.asarray(vals1, np.float64)[:, k - 1], t0)
+        with span("prune.probe"):
+            P1 = min(n_phase1, n_cand)
+            ubm = np.where(in_term, ub, -1.0)
+            top = np.argpartition(-ubm, P1 - 1, axis=1)[:, :P1]
+            p1_act = np.take_along_axis(in_term, top, 1)
+            probed = _pow2ceil(B * P1)
+            p1_ids = np.zeros(probed, np.int32)
+            p1_idf = np.zeros(probed, np.float32)
+            p1_actf = np.zeros(probed, bool)
+            p1_row = np.zeros(probed, np.int32)
+            p1_ids[:B * P1] = np.take_along_axis(bidx, top, 1).reshape(-1)
+            p1_idf[:B * P1] = np.take_along_axis(idf_pb, top, 1).reshape(-1)
+            p1_actf[:B * P1] = p1_act.reshape(-1)
+            p1_row[:B * P1] = np.repeat(np.arange(B, dtype=np.int32), P1)
+            vals1, _ = scorer_for(probed)(p1_ids, p1_idf, p1_actf, p1_row)
+            theta = np.maximum(np.asarray(vals1, np.float64)[:, k - 1], t0)
     else:
         theta = t0
 
@@ -704,73 +758,36 @@ def pruned_eval(meta, scorer_for, q2d, idf2d, k: int, theta0=None,
     # unconditionally either way: the impact bound can be exactly
     # achieved (the block's best doc IS its (max_tf, min_dl) pair), so a
     # probed doc at exactly theta must stay scored.
-    Q = q2d.shape[1]
-    ub3 = ub.reshape(B, Q, -1)
-    MB = ub3.shape[2]
-    term_best = ub3.max(axis=2)                            # (B, Q)
-    n_elim = 0
-    if bmw:
-        # doc-range-overlap "others" bound. Pad entries get a sentinel
-        # extent past every real doc id: rows stay sorted (in_term is a
-        # prefix mask, pads trail) and sentinel ranges only overlap other
-        # sentinel ranges, whose UB is 0.
-        in3 = in_term.reshape(B, Q, MB)
-        sentinel = int(max(np.asarray(bl_d).max(initial=0),
-                           np.asarray(bf_d).max(initial=0)) + 1)
-        f3 = np.where(in3, np.asarray(bf_d, np.int64).reshape(B, Q, MB),
-                      sentinel)
-        l3 = np.where(in3, np.asarray(bl_d, np.int64).reshape(B, Q, MB),
-                      sentinel)
-        bound3 = ub3 + _bmw_overlap_others(ub3, f3, l3, sentinel)
-        base = in3 & (bound3 > theta[:, None, None])
-        # non-essential list elimination: sort terms by ascending best
-        # contribution; the maximal prefix whose cumulative sum cannot
-        # beat theta is non-essential. A winner (true score > theta) must
-        # carry >= 1 essential term, so non-essential terms generate no
-        # candidates of their own — their blocks are kept only when they
-        # range-overlap a SURVIVING essential block (those are the only
-        # places a winner's remaining contributions can live).
-        order = np.argsort(term_best, axis=1, kind="stable")
-        csum = np.cumsum(np.take_along_axis(term_best, order, 1), axis=1)
-        ness = np.zeros((B, Q), bool)
-        np.put_along_axis(ness, order, csum <= theta[:, None], 1)
-        has_blocks = in3.any(axis=2)
-        n_elim = int((ness & has_blocks).sum())
-        if ness.any():
-            ess_surv = (base & ~ness[:, :, None]).astype(np.float64)
-            touches = _bmw_overlap_others(ess_surv, f3, l3, sentinel) > 0
-            base = np.where(ness[:, :, None], base & touches, base)
-        surv = base.reshape(B, -1)
-        bound = bound3.reshape(B, -1)
-    else:
-        # term-level MaxScore baseline: every other term helps with its
-        # global best block, wherever that block lives in doc space
-        others = term_best.sum(axis=1, keepdims=True) - term_best
-        bound = (ub3 + others[:, :, None]).reshape(B, -1)
-        surv = in_term & (bound > theta[:, None])
-    if top is not None:
-        surv[np.arange(B)[:, None], top] |= p1_act
-        # probe blocks carry the unconditional-keep contract into the
-        # midgrid kernel too: their stored UB becomes +inf so the in-grid
-        # skip test can never drop them. (Their host bound can sit an ulp
-        # BELOW theta — f64 bound vs f32 scoring — which is exactly the
-        # tie case the unconditional keep exists to cover.)
-        rows_b = np.repeat(np.arange(B), top.shape[1])
-        cols_b = top.reshape(-1)
-        keepmask = p1_act.reshape(-1)
-        bound[rows_b[keepmask], cols_b[keepmask]] = np.inf
-    n_surv = int(surv.sum())
-    cb_ids, cb_idf, cb_act, cb_row, cb_ubf = compact_survivors(
-        surv, bidx, idf_pb, ubf=bound)
+    with span("prune.bound"):
+        surv, bound, n_elim = _bound_test(ub, in_term, bf, bl, theta,
+                                          q2d.shape[1], bmw)
+        if top is not None:
+            surv[np.arange(B)[:, None], top] |= p1_act
+            # probe blocks carry the unconditional-keep contract into the
+            # midgrid kernel too: their stored UB becomes +inf so the
+            # in-grid skip test can never drop them. (Their host bound
+            # can sit an ulp BELOW theta — f64 bound vs f32 scoring —
+            # which is exactly the tie case the unconditional keep
+            # exists to cover.)
+            rows_b = np.repeat(np.arange(B), top.shape[1])
+            cols_b = top.reshape(-1)
+            keepmask = p1_act.reshape(-1)
+            bound[rows_b[keepmask], cols_b[keepmask]] = np.inf
+        n_surv = int(surv.sum())
+    with span("prune.compact"):
+        cb_ids, cb_idf, cb_act, cb_row, cb_ubf = compact_survivors(
+            surv, bidx, idf_pb, ubf=bound)
     n_skipped = 0
-    if scorer_mid_for is not None:
-        vals, ids, n_skip = scorer_mid_for(cb_ids.shape[0])(
-            cb_ids, cb_idf, cb_act, cb_row, cb_ubf,
-            theta.astype(np.float32))
-        n_skipped = int(n_skip)
-    else:
-        vals, ids = scorer_for(cb_ids.shape[0])(cb_ids, cb_idf, cb_act,
-                                                cb_row)
+    with span("score.survivors"):
+        if scorer_mid_for is not None:
+            vals, ids, n_skip = scorer_mid_for(cb_ids.shape[0])(
+                cb_ids, cb_idf, cb_act, cb_row, cb_ubf,
+                theta.astype(np.float32))
+            n_skipped = int(n_skip)
+        else:
+            vals, ids = scorer_for(cb_ids.shape[0])(cb_ids, cb_idf, cb_act,
+                                                    cb_row)
+        vals, ids = np.asarray(vals), np.asarray(ids)
     # queries/batches stay zero here: this evaluates ONE segment of a
     # batch; the caller (searcher / bm25_topk) counts the batch once.
     stats = PruneStats(

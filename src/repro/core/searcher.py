@@ -67,6 +67,7 @@ from repro.core.query import (BLOCK, MIDGRID_MAX_K, BlockMaxIndex,
 from repro.core.segments import Segment, live_posting_stats
 from repro.kernels.postings_pack import ops as pack_ops
 from repro.kernels.postings_pack import ref as pack_ref
+from repro.spans import span
 
 
 # --------------------------------------------------------------------------
@@ -833,12 +834,15 @@ class IndexSearcher:
         results strictly above the seed are exact; docs at or below it may
         be dropped, but >= k better ones exist elsewhere by assertion."""
         B = q2d.shape[0]
-        idf = self.global_idf(q2d)
         stats = PruneStats(queries=B, batches=1)
-        live = [(r, dn) for r, dn in zip(self.readers, self._doc_norms)
-                if min(k, r.live_docs) > 0 and r.terms_np.size > 0]
-        seg_ub = [r.query_max_ub(q2d, idf, self.avgdl) for r, _ in live]
-        order = np.argsort([-float(u.sum()) for u in seg_ub], kind="stable")
+        with span("search.plan"):
+            idf = self.global_idf(q2d)
+            live = [(r, dn) for r, dn in zip(self.readers, self._doc_norms)
+                    if min(k, r.live_docs) > 0 and r.terms_np.size > 0]
+            seg_ub = [r.query_max_ub(q2d, idf, self.avgdl)
+                      for r, _ in live]
+            order = np.argsort([-float(u.sum()) for u in seg_ub],
+                               kind="stable")
         ext_theta = theta0 is not None
         theta0 = (np.zeros(B, np.float64) if theta0 is None else
                   np.array(np.broadcast_to(
@@ -853,33 +857,35 @@ class IndexSearcher:
                     and bool(np.all(seg_ub[oi] < theta0)):
                 stats.segments_skipped += 1
                 continue  # nothing inside can beat the running top-k
-            mb = r.query_max_blocks(q2d)
-            v, i, st = r.topk_pruned(q2d, idf, dn, k_eff, mb, theta0=theta0,
-                                     avgdl=self.avgdl, bmw=self.bmw,
-                                     midgrid=self.midgrid)
-            stats.add(st)
-            v_np = np.asarray(v)
-            parts_v.append(v_np)
-            parts_i.append(np.asarray(i))
-            running = v_np if running is None \
-                else np.concatenate([running, v_np], axis=1)
-            if running.shape[1] > k:
-                running = -np.partition(-running, k - 1, axis=1)[:, :k]
-            if running.shape[1] >= k:
-                theta0 = np.maximum(theta0, running.min(axis=1))
+            with span("search.segment", seg=r.seg_id):
+                mb = r.query_max_blocks(q2d)
+                v, i, st = r.topk_pruned(q2d, idf, dn, k_eff, mb,
+                                         theta0=theta0, avgdl=self.avgdl,
+                                         bmw=self.bmw, midgrid=self.midgrid)
+                stats.add(st)
+                parts_v.append(v)
+                parts_i.append(i)
+                running = v if running is None \
+                    else np.concatenate([running, v], axis=1)
+                if running.shape[1] > k:
+                    running = -np.partition(-running, k - 1, axis=1)[:, :k]
+                if running.shape[1] >= k:
+                    theta0 = np.maximum(theta0, running.min(axis=1))
         with self._stats_lock:
             self.prune_stats.add(stats)
         if not parts_v:
             return self._empty((B,), k)
-        vals = jnp.asarray(np.concatenate(parts_v, axis=1))
-        ids = jnp.asarray(np.concatenate(parts_i, axis=1))
-        kk = min(k, vals.shape[1])
-        top_v, pos = jax.lax.top_k(vals, kk)
-        top_i = jnp.take_along_axis(ids, pos, axis=1)
-        if kk < k:
-            top_v = jnp.pad(top_v, ((0, 0), (0, k - kk)))
-            top_i = jnp.pad(top_i, ((0, 0), (0, k - kk)), constant_values=-1)
-        return top_v, top_i
+        with span("search.merge"):
+            vals = jnp.asarray(np.concatenate(parts_v, axis=1))
+            ids = jnp.asarray(np.concatenate(parts_i, axis=1))
+            kk = min(k, vals.shape[1])
+            top_v, pos = jax.lax.top_k(vals, kk)
+            top_i = jnp.take_along_axis(ids, pos, axis=1)
+            if kk < k:
+                top_v = jnp.pad(top_v, ((0, 0), (0, k - kk)))
+                top_i = jnp.pad(top_i, ((0, 0), (0, k - kk)),
+                                constant_values=-1)
+            return np.asarray(top_v), np.asarray(top_i)
 
     def search(self, q_terms, k: int = 10):
         """Top-k over every live segment; returns (scores (k,), doc_ids (k,))

@@ -50,6 +50,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.query import PruneStats
+from repro.spans import span
 
 
 class Overloaded(RuntimeError):
@@ -232,35 +233,37 @@ class QueryScheduler:
                 return []
             batch = self.queue[:self.slots]
             del self.queue[:len(batch)]
-        B = self.slots if self.full_batch else _bucket(len(batch),
-                                                       self.slots)
-        q = np.full((B, self.max_terms), -1, np.int32)
-        for i, req in enumerate(batch):
-            t = np.asarray(req.terms, np.int32)
-            q[i, :len(t)] = t
-        # one capture: results and cache key come from the same searcher
-        # object. An IndexSearcher is an immutable snapshot, so the key
-        # is exact by construction; a FleetSearcher is mutable, so the
-        # key is re-read after serving and a change (a replica synced
-        # mid-batch) vetoes the cache fill.
-        searcher = self.searcher
-        gen = getattr(searcher, "generation", 0)
-        vals, ids = searcher.search_batched(q, self.k)
-        vals, ids = np.asarray(vals), np.asarray(ids)
-        t_done = time.perf_counter()
-        cacheable = (self.cache is not None and gen
-                     and getattr(searcher, "generation", 0) == gen)
-        for i, req in enumerate(batch):
-            if cacheable:
-                self.cache.put((self._cache_key(req), gen),
-                               (vals[i].copy(), ids[i].copy()))
-            kk = min(req.k, self.k)
-            req.scores, req.doc_ids = vals[i, :kk], ids[i, :kk]
-            req.done = True
-            req.t_done = t_done
+            self.steps += 1
+            step = self.steps
+        with span("sched.step", step=step, batch=len(batch)):
+            B = self.slots if self.full_batch else _bucket(len(batch),
+                                                           self.slots)
+            q = np.full((B, self.max_terms), -1, np.int32)
+            for i, req in enumerate(batch):
+                t = np.asarray(req.terms, np.int32)
+                q[i, :len(t)] = t
+            # one capture: results and cache key come from the same
+            # searcher object. An IndexSearcher is an immutable snapshot,
+            # so the key is exact by construction; a FleetSearcher is
+            # mutable, so the key is re-read after serving and a change
+            # (a replica synced mid-batch) vetoes the cache fill.
+            searcher = self.searcher
+            gen = getattr(searcher, "generation", 0)
+            vals, ids = searcher.search_batched(q, self.k)
+            vals, ids = np.asarray(vals), np.asarray(ids)
+            t_done = time.perf_counter()
+            cacheable = (self.cache is not None and gen
+                         and getattr(searcher, "generation", 0) == gen)
+            for i, req in enumerate(batch):
+                if cacheable:
+                    self.cache.put((self._cache_key(req), gen),
+                                   (vals[i].copy(), ids[i].copy()))
+                kk = min(req.k, self.k)
+                req.scores, req.doc_ids = vals[i, :kk], ids[i, :kk]
+                req.done = True
+                req.t_done = t_done
         with self._lock:
             self.served += len(batch)
-            self.steps += 1
             if len(batch) < self.slots:
                 self.partial_steps += 1
         return batch

@@ -63,6 +63,7 @@ import numpy as np
 
 from repro.core.segments import Segment
 from repro.kernels.postings_pack import ref as pack_ref
+from repro.spans import span
 
 MAGIC = b"RSEG"
 VERSION = 2
@@ -652,7 +653,8 @@ def write_segment(directory, name: str, seg: Segment,
                   codec: str = "pfor") -> int:
     """Encode ``seg`` into ``directory`` as ``<name><suffix>`` files;
     returns the encoded byte total (what actually crossed the device)."""
-    files = encode_segment(seg, codec)
+    with span("codec.encode"):
+        files = encode_segment(seg, codec)
     return sum(directory.write_file(name + sfx, data)
                for sfx, data in files.items())
 

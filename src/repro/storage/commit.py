@@ -40,6 +40,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from repro.spans import span
 from repro.storage import codec as seg_codec
 from repro.storage.codec import (CorruptSegment, KIND_MANIFEST,
                                  decode_liveness, encode_liveness, frame,
@@ -499,7 +500,12 @@ class SegmentStore:
         name first — the in-memory Segment is authoritative, so a live
         writer recovers from bit rot with zero loss; the corrupt files
         are superseded and deleted like any dead segment's."""
-        live_segments = list(live_segments)
+        with span("store.commit") as sp:
+            gen = self._commit(list(live_segments))
+            sp.set_metadata(gen=gen)
+        return gen
+
+    def _commit(self, live_segments) -> int:
         with self._lock:
             quarantined_now = set(self.quarantined)
         if quarantined_now:

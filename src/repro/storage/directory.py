@@ -43,6 +43,8 @@ import threading
 import time
 from dataclasses import dataclass
 
+from repro.spans import span
+
 
 class Directory:
     """Abstract flat byte store with measured-IO accounting.
@@ -64,10 +66,11 @@ class Directory:
     # -- accounting wrappers ------------------------------------------------
     def write_file(self, name: str, data: bytes) -> int:
         _check_name(name)
-        data = bytes(data)
-        t0 = time.perf_counter()
-        self._write(name, data)
-        dt = time.perf_counter() - t0
+        with span("directory.write"):
+            data = bytes(data)
+            t0 = time.perf_counter()
+            self._write(name, data)
+            dt = time.perf_counter() - t0
         with self._acct_lock:
             self.bytes_written += len(data)
             self.write_wall_s += dt
@@ -112,9 +115,10 @@ class Directory:
         for n in names:   # the barrier contract holds on every backend
             if n not in existing:
                 raise FileNotFoundError(n)
-        t0 = time.perf_counter()
-        self._sync(names)
-        dt = time.perf_counter() - t0
+        with span("directory.sync"):
+            t0 = time.perf_counter()
+            self._sync(names)
+            dt = time.perf_counter() - t0
         with self._acct_lock:
             self.syncs += len(names)
             self.sync_wall_s += dt
