@@ -724,6 +724,7 @@ class DistributedIndexer:
             "prune_skip_rate": ps.skip_rate,
             "terms_eliminated": ps.terms_eliminated,
             "blocks_skipped_midgrid": ps.blocks_skipped_midgrid,
+            "blocks_margin_kept": ps.blocks_margin_kept,
             "evaluator_cache_hits": evaluator_cache_hits(),
         })
         # fault-tolerance surface: is this index serving with holes, and

@@ -25,8 +25,9 @@ Two implementations share that contract:
 
 ``bm25_topk``        the production pruned path: a cheap jittable
     *metadata* pass (``prune_candidates`` — per-block upper bounds, no
-    postings decode) feeds a host-side MaxScore test, the surviving block
-    ids are **compacted** (gathered into a dense array, padded to a
+    postings decode) feeds the bound test (``prune_decide``, jitted, on
+    the metadata's device arrays), the surviving block ids are
+    **compacted** on the device (gathered into a dense array, padded to a
     power-of-two bucket so compiled shapes stay bounded), and only the
     compacted blocks are decoded + scored (``score_survivors``). Cost is
     proportional to *surviving* blocks — the first serving path that is
@@ -46,6 +47,7 @@ searcher can thread the running global k-th score across segments
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -152,6 +154,10 @@ class PruneStats:
                           kernel's in-grid theta tightening: their stored
                           full-score UB fell below the running k-th-best
                           lower bound folded from earlier grid steps.
+    ``blocks_margin_kept``  candidate blocks that pass the float32 bound
+                          test only through its rounding slack
+                          (``bound * (1 + slack) > theta >= bound``): the
+                          work the device test's safety margin costs.
     """
 
     queries: int = 0
@@ -163,12 +169,10 @@ class PruneStats:
     blocks_scored: int = 0
     terms_eliminated: int = 0
     blocks_skipped_midgrid: int = 0
+    blocks_margin_kept: int = 0
 
     def add(self, other: "PruneStats") -> None:
-        for f in ("queries", "batches", "segments_visited",
-                  "segments_skipped", "blocks_candidate", "blocks_survived",
-                  "blocks_scored", "terms_eliminated",
-                  "blocks_skipped_midgrid"):
+        for f in self.__dataclass_fields__:
             setattr(self, f, getattr(self, f) + getattr(other, f))
 
     def snapshot(self) -> "PruneStats":
@@ -386,9 +390,9 @@ def prune_candidates(index: BlockMaxIndex, q_terms, idf_q=None,
     doc_norm — required for the tight impact bounds; None falls back to
     the safe dl=0 floor (see ``block_upper_bounds``). Returns
     ``(ub, in_term, bidx, idf_pb, bfirst, blast)``, each shaped (Q, MB) —
-    the inputs of the host-side BMW overlap-bound test and survivor
+    the inputs of the device bound test (``prune_decide``) and survivor
     compaction. ``bfirst``/``blast`` are the candidate blocks' doc-id
-    extents (garbage on pad entries — the host masks by ``in_term``); an
+    extents (garbage on pad entries — the test masks by ``in_term``); an
     index without ``last_doc`` reports the safe full-range extent
     [first, n_docs-1] instead, degrading the overlap bound toward the
     term-level one without ever under-bounding."""
@@ -492,40 +496,374 @@ def survivor_bucket(n_surv: int) -> int:
     return max(MIN_BUCKET, _pow2ceil(max(n_surv, 1)))
 
 
-def compact_survivors(surv: np.ndarray, bidx: np.ndarray, idf_pb: np.ndarray,
-                      bucket: int = None, ubf: np.ndarray = None):
-    """Host-side survivor compaction: gather the flattened positions of
-    surviving candidate blocks — across the WHOLE batch — into one dense,
-    bucket-padded flat array with per-entry query-row attribution.
+# float32 slack of the device bound test. The device sums float32 upper
+# bounds in float32, so a bound can round below its exact value by up to
+# (Q-1) * 2^-24 relative, and the product by (1 + slack) rounds once more:
+# a slack of 2^-20 covers queries of up to 15 terms (longer ones get
+# more), so the device keeps every block the exact test keeps.
+BOUND_SLACK = 2.0 ** -20
 
-    ``surv``/``bidx``/``idf_pb`` are (B, N) host arrays over the flattened
-    candidate grid. ``np.flatnonzero`` over the row-major matrix yields
-    entries sorted by (row, grid position), which keeps each row's
-    compacted scatter contributions in the dense path's order (bit-
-    identity), and sizes the bucket by the batch's total survivor count.
-    ``ubf`` (B, N), optional, is each block's full-score upper bound (the
-    BMW bound the survival test used) — the midgrid kernel compares it
-    against its running k-th-best carry; None stores +inf (never
-    midgrid-skipped). Returns ``(cb_ids, cb_idf, cb_act, cb_row,
-    cb_ubf)``, each shaped (bucket,)."""
-    B, N = surv.shape
-    pos = np.flatnonzero(surv)
-    if bucket is None:
-        bucket = survivor_bucket(pos.size)
-    assert pos.size <= bucket, "survivors must never be truncated"
-    cb_ids = np.zeros(bucket, np.int32)
-    cb_idf = np.zeros(bucket, np.float32)
-    cb_act = np.zeros(bucket, bool)
-    cb_row = np.zeros(bucket, np.int32)
-    cb_ubf = np.full(bucket, np.inf, np.float32)
-    cb_ids[:pos.size] = bidx.reshape(-1)[pos]
-    cb_idf[:pos.size] = idf_pb.reshape(-1)[pos]
-    cb_act[:pos.size] = True
-    cb_row[:pos.size] = pos // N
-    if ubf is not None:
-        cb_ubf[:pos.size] = ubf.reshape(-1)[pos]
-    return cb_ids, cb_idf, cb_act, cb_row, cb_ubf
 
+def bound_slack(n_terms: int) -> float:
+    """Relative slack of the float32 bound test for ``n_terms`` terms."""
+    return BOUND_SLACK * max(1, _pow2ceil(n_terms + 1) // 16)
+
+
+def _best_overlapping(f3, l3, x):
+    """For every block j of term t: the sum over the OTHER terms `to` of
+    ``max x[to, i]`` over `to`'s blocks i whose doc-id range [f, l] meets
+    j's (0 where none does). ``f3``/``l3``/``x`` are (B, Q, MB); ``x``
+    must be 0 on pad blocks. Every (j, i) pair is compared at once and
+    reduced in place — a compare-and-max fusion, no gather — one other
+    term at a time, so nothing of size MB^2 is held."""
+    B, Q, MB = x.shape
+    fj, lj = f3[..., None], l3[..., None]                  # (B, t, j, 1)
+    terms = jnp.arange(Q)[None, :, None]
+    out = jnp.zeros(x.shape, x.dtype)
+    for to in range(Q):
+        meets = ((l3[:, to, None, None, :] >= fj)
+                 & (f3[:, to, None, None, :] <= lj))       # (B, t, j, i)
+        best = jnp.where(meets, x[:, to, None, None, :], 0).max(-1)
+        out = out + jnp.where(terms != to, best, 0)
+    return out
+
+
+def bound_test(ub, in_term, bf, bl, theta, bmw: bool):
+    """Phase 2 of ``pruned_eval`` on the device: the blocks that survive
+    the bound test at ``theta`` (B,), float32 throughout.
+
+    ``ub``/``in_term``/``bf``/``bl`` are the metadata pass's (B, Q, MB)
+    arrays (``bf``/``bl``, the blocks' first and last doc ids, read only
+    by ``bmw``). ``bmw`` runs the doc-range-overlap bound with
+    non-essential term elimination, else the term-level MaxScore test.
+    A block survives iff ``bound * (1 + slack) > theta``, and a term is
+    non-essential only if its prefix sum times ``(1 + slack)`` is still
+    ``<= theta`` (``bound_slack``): the survivors are a superset of the
+    exact (float64) test's and the non-essential terms a subset, so the
+    float32 rounding can cost work but never a result.
+
+    Returns ``(surv (B, Q, MB) bool, bound (B, Q, MB) float32 — without
+    the slack, non-essential (B, Q) bool, blocks kept only by the slack
+    (scalar))``."""
+    B, Q, MB = ub.shape
+    grow = jnp.float32(1.0 + bound_slack(Q))
+    ub = jnp.where(in_term, ub, 0.0)
+    term_best = ub.max(axis=2)                             # (B, Q)
+    if bmw:
+        # doc-range-overlap "others" bound: a doc of block j that also
+        # carries term `to` sits in one of `to`'s blocks, whose range
+        # therefore meets j's
+        bound = ub + _best_overlapping(bf, bl, ub)
+    else:
+        # term-level MaxScore: every other term helps with its global
+        # best block, wherever that block lives in doc space
+        others = jnp.where(~jnp.eye(Q, dtype=bool)[None],
+                           term_best[:, :, None], 0.0).sum(1)
+        bound = ub + others[:, :, None]
+    theta3 = theta[:, None, None]
+    surv = in_term & (bound * grow > theta3)
+    n_margin = (surv & (theta3 >= bound)).sum()
+    ness = jnp.zeros((B, Q), bool)
+    if bmw:
+        # non-essential list elimination: sort terms by ascending best
+        # contribution; the maximal prefix whose cumulative sum cannot
+        # beat theta is non-essential. A winner (true score > theta) must
+        # carry >= 1 essential term, so non-essential terms generate no
+        # candidates of their own — their blocks are kept only when they
+        # range-overlap a SURVIVING essential block (those are the only
+        # places a winner's remaining contributions can live).
+        order = jnp.argsort(term_best, axis=1, stable=True)
+        csum = jnp.cumsum(jnp.take_along_axis(term_best, order, 1), 1)
+        ness = jnp.take_along_axis(csum * grow <= theta[:, None],
+                                   jnp.argsort(order, axis=1), 1)
+        ess_surv = (surv & ~ness[:, :, None]).astype(jnp.int32)
+        touches = _best_overlapping(bf, bl, ess_surv) > 0
+        surv = jnp.where(ness[:, :, None], surv & touches, surv)
+    return surv, bound, ness, n_margin
+
+
+@functools.partial(jax.jit, static_argnames=("n_phase1",))
+def probe_pick(ub, in_term, bidx, idf_pb, n_phase1: int):
+    """Phase 1's probe on the device: per query the ``n_phase1``
+    highest-UB candidate blocks, compacted into one flat batch list
+    (padded to a power of two) for the survivor scorer. Returns
+    ``((ids, idf, act, row), pos (B, P1), act (B, P1))``; ``pos``/``act``
+    mark the probe blocks that ``prune_decide`` keeps unconditionally."""
+    B = ub.shape[0]
+    ub, in_term = ub.reshape(B, -1), in_term.reshape(B, -1)
+    _, pos = jax.lax.top_k(jnp.where(in_term, ub, -1.0), n_phase1)
+    act = jnp.take_along_axis(in_term, pos, 1)
+    pad = _pow2ceil(B * n_phase1) - B * n_phase1
+
+    def flat(x):
+        return jnp.pad(jnp.take_along_axis(x.reshape(B, -1), pos, 1
+                                           ).reshape(-1), (0, pad))
+    row = jnp.repeat(jnp.arange(B, dtype=jnp.int32), n_phase1)
+    return ((flat(bidx), flat(idf_pb), jnp.pad(act.reshape(-1), (0, pad)),
+             jnp.pad(row, (0, pad))), pos, act)
+
+
+@jax.jit
+def probe_theta(vals, theta0):
+    """theta (B,) = max(the probe's k-th best score, ``theta0``): a valid
+    lower bound on each query's final k-th score."""
+    return jnp.maximum(vals[:, -1], theta0)
+
+
+@functools.partial(jax.jit, static_argnames=("bmw",))
+def prune_decide(ub, in_term, bf, bl, theta, keep_pos, keep_act, bmw: bool):
+    """The pruning decision of one segment, on the device: the bound test
+    at ``theta`` (B,) (``bound_test``), and the phase-1 probe blocks
+    (``keep_pos`` (B, P1) where ``keep_act``) kept unconditionally: the
+    impact bound can be exactly achieved (the block's best doc IS its
+    (max_tf, min_dl) pair), so a probed doc at exactly theta must stay
+    scored. Without a probe the caller passes zeros.
+
+    Returns ``(rank (B, N), ubf (B, N), counts)``: ``rank`` counts the
+    survivors up to and including each candidate of the row-major
+    flattened grid (what ``compact_survivors`` reads); ``ubf`` is each
+    block's slack-inflated bound, +inf on the probe blocks so the midgrid
+    kernel's in-grid skip can never drop them; ``counts`` (int32 (4,))
+    holds the survivors, the candidate blocks, the non-essential terms
+    that have blocks and ``blocks_margin_kept`` — the one fetch the host
+    needs."""
+    B, Q, MB = ub.shape
+    surv, bound, ness, n_margin = bound_test(ub, in_term, bf, bl, theta,
+                                             bmw)
+    rows = jnp.arange(B)[:, None]
+    surv = surv.reshape(B, -1)
+    surv = surv.at[rows, keep_pos].set(surv[rows, keep_pos] | keep_act)
+    ubf = (bound * jnp.float32(1.0 + bound_slack(Q))).reshape(B, -1)
+    ubf = ubf.at[rows, keep_pos].set(
+        jnp.where(keep_act, jnp.inf, ubf[rows, keep_pos]))
+    # inclusive running count in row-major order: per row, then the rows
+    # before it (one long cumsum compiles far slower on the TPU)
+    rank = jnp.cumsum(surv.astype(jnp.int32), 1)
+    rank = rank + (jnp.cumsum(rank[:, -1]) - rank[:, -1])[:, None]
+    counts = jnp.stack([rank[-1, -1], in_term.sum(),
+                        (ness & in_term.any(2)).sum(), n_margin]
+                       ).astype(jnp.int32)
+    return rank, ubf, counts
+
+
+@functools.partial(jax.jit, static_argnames=("bucket",))
+def compact_survivors(rank, bidx, idf_pb, ubf, bucket: int):
+    """Survivor compaction on the device: gather the flattened positions
+    of surviving candidate blocks — across the WHOLE batch — into one
+    dense, bucket-padded flat list with per-entry query-row attribution.
+
+    ``rank``/``ubf`` are (B, N) over the flattened candidate grid
+    (``prune_decide``), ``bidx``/``idf_pb`` the metadata's (B, Q, MB).
+    Entry j is the candidate where the row-major running count first
+    reaches j + 1, so entries are sorted by (row, grid position), which
+    keeps each row's compacted scatter contributions in the dense path's
+    order (bit-identity); ``bucket`` (``survivor_bucket`` of the fetched
+    count) must hold every survivor. Returns ``(cb_ids, cb_idf, cb_act,
+    cb_row, cb_ubf)``, each (bucket,); padding entries are inactive, with
+    UB +inf (never midgrid-skipped)."""
+    N = rank.shape[1]
+    rank = rank.reshape(-1)
+    j = jnp.arange(bucket, dtype=jnp.int32)
+    act = j < rank[-1]
+    pos = jnp.where(act, jnp.searchsorted(rank, j + 1, method="scan"), 0)
+    return (jnp.where(act, bidx.reshape(-1)[pos], 0),
+            jnp.where(act, idf_pb.reshape(-1)[pos], 0.0), act,
+            jnp.where(act, pos // N, 0).astype(jnp.int32),
+            jnp.where(act, ubf.reshape(-1)[pos], jnp.inf))
+
+
+def _theta_f32(theta0, B: int) -> np.ndarray:
+    """(B,) float64 host bound -> float32, rounded down: a lower theta
+    only keeps more blocks."""
+    t = np.broadcast_to(np.asarray(theta0, np.float64), (B,))
+    t32 = t.astype(np.float32)
+    return np.where(t32 > t, np.nextafter(t32, np.float32(-np.inf)), t32)
+
+
+def pruned_eval(meta, scorer_for, q2d, idf2d, theta0=None,
+                n_phase1: int = PHASE1_BLOCKS, bmw: bool = True,
+                scorer_mid_for=None):
+    """Pruned evaluation of one segment over a (B, Q) query batch, the
+    decision on the device.
+
+    ``meta(q2d, idf2d)``       -> (ub, in_term, bidx, idf_pb, bfirst,
+                                  blast), (B, Q, MB) device arrays
+                                  (``prune_candidates``, possibly
+                                  jitted/vmapped by the caller).
+    ``scorer_for(n_blocks)``   -> fn(cb_ids, cb_idf, cb_act, cb_row)
+                                  evaluating a flat (n_blocks,) compacted
+                                  survivor list (``score_survivors``) to
+                                  (vals (B, k), ids (B, k)). The caller
+                                  owns jit caching per bucket shape.
+    ``scorer_mid_for``         optional midgrid variant for the SURVIVOR
+                                  stage: fn(cb_ids, cb_idf, cb_act,
+                                  cb_row, cb_ubf, theta_rows) -> (vals,
+                                  ids, n_skipped) — the kernel folds a
+                                  running k-th-best lower bound across
+                                  grid steps and zeroes later blocks
+                                  whose stored full-score UB ``cb_ubf``
+                                  falls below it (see
+                                  ``score_survivors_midgrid``). The
+                                  phase-1 probe always uses the plain
+                                  scorer (theta is not known yet).
+    ``theta0``                 (B,) or scalar: an externally-known lower
+                                  bound on each query's final k-th score
+                                  (the searcher passes the running global
+                                  bound — cross-segment theta sharing).
+    ``bmw``                    True (default) runs the doc-range-overlap
+                                  bound + non-essential list elimination;
+                                  False keeps the term-level MaxScore
+                                  test (the bench A/B baseline).
+
+    Protocol, all on the device: metadata pass -> score the ``n_phase1``
+    highest-UB blocks per query for theta (``probe_pick``; skipped
+    entirely when every query already holds a positive external bound) ->
+    block-max WAND test at max(theta_phase1, theta0) (``prune_decide``)
+    -> compact the survivors (``compact_survivors``, a power-of-two bucket
+    over the batch TOTAL) -> compacted exact scoring. The host fetches
+    the decision's counts, which pick the bucket, and the final top-k.
+
+    Exactness under BMW: for a doc d with true score > theta, every block
+    of d survives — the block's own UB majorizes d's contribution from
+    that term, and for every OTHER query term d carries, d's block there
+    shares d and therefore range-overlaps, so its UB enters the overlap
+    sum: bound >= true(d) > theta. Non-essential elimination preserves
+    this: a doc scoring above theta must have at least one essential term
+    (the non-essential prefix's term-best sum is <= theta by
+    construction), its essential blocks survive the bound test, and its
+    non-essential blocks range-overlap one of them — the condition under
+    which non-essential blocks are kept. Docs at or below theta may end
+    up partially scored, but their computed score never exceeds their
+    true score, so any value the final top-k surfaces is exact (ties at
+    theta are covered by the unconditionally-kept phase-1 probes / the
+    ``theta0`` securing contract). The float32 slack (``bound_test``)
+    only adds survivors.
+    Returns ``(vals, ids, PruneStats)``, ``vals``/``ids`` fetched to the
+    host.
+    """
+    B, Q = q2d.shape
+    with span("prune.meta"):
+        ub, in_term, bidx, idf_pb, bf, bl = jax.block_until_ready(
+            meta(q2d, idf2d))
+    t0 = _theta_f32(0.0 if theta0 is None else theta0, B)
+    P1 = min(n_phase1, ub.shape[1] * ub.shape[2])
+
+    # phase 1: probe the highest-UB blocks for a threshold. The probe set
+    # is compacted too (fixed shape P1), so phase-1 cost is O(P1), not
+    # O(candidates)/2 like the dense oracle's. A caller that already
+    # holds a positive bound for every query (the searcher's shared theta
+    # after the first segment) skips the probe entirely — later segments
+    # pay ONLY for their survivors.
+    probed = 0
+    if not bool(np.all(t0 > 0)):
+        with span("prune.probe"):
+            p1, keep_pos, keep_act = probe_pick(ub, in_term, bidx, idf_pb,
+                                                n_phase1=P1)
+            probed = p1[0].shape[0]
+            vals1, _ = scorer_for(probed)(*p1)
+            theta = jax.block_until_ready(probe_theta(vals1, t0))
+    else:
+        theta = t0
+        keep_pos = np.zeros((B, P1), np.int32)
+        keep_act = np.zeros((B, P1), bool)
+
+    with span("prune.bound"):
+        rank, ubf, counts = prune_decide(
+            ub, in_term, bf, bl, theta, keep_pos, keep_act, bmw=bmw)
+        n_surv, n_cand, n_elim, n_margin = jax.device_get(counts).tolist()
+    with span("prune.compact"):
+        cb_ids, cb_idf, cb_act, cb_row, cb_ubf = compact_survivors(
+            rank, bidx, idf_pb, ubf, bucket=survivor_bucket(n_surv))
+    n_skipped = 0
+    with span("score.survivors"):
+        if scorer_mid_for is not None:
+            out = scorer_mid_for(cb_ids.shape[0])(
+                cb_ids, cb_idf, cb_act, cb_row, cb_ubf, theta)
+            vals, ids, n_skipped = jax.device_get(out)
+        else:
+            vals, ids = jax.device_get(scorer_for(cb_ids.shape[0])(
+                cb_ids, cb_idf, cb_act, cb_row))
+    # queries/batches stay zero here: this evaluates ONE segment of a
+    # batch; the caller (searcher / bm25_topk) counts the batch once.
+    stats = PruneStats(
+        segments_visited=1,
+        blocks_candidate=n_cand,
+        blocks_survived=n_surv,
+        blocks_scored=probed + cb_ids.shape[0],
+        terms_eliminated=n_elim,
+        blocks_skipped_midgrid=int(n_skipped),
+        blocks_margin_kept=n_margin)
+    return vals, ids, stats
+
+
+def bm25_topk(index: BlockMaxIndex, q_terms: jnp.ndarray, k: int = 10,
+              prune: bool = True, idf_q=None, doc_norm=None,
+              max_blocks=None, live=None, theta0=None, avgdl=None,
+              bmw: bool = True, midgrid: bool = True):
+    """Top-k BM25: ``(scores (k,), doc_ids (k,), stats dict)``.
+
+    ``prune=True`` runs the compacted pruned path (its decision runs on
+    the device, but the host picks each survivor bucket from a fetched
+    count, so this function itself is NOT jittable — the searcher caches
+    jitted versions of its metadata pass and scorers); ``prune=False``
+    falls back to the dense exhaustive evaluation. Results are identical
+    either way. See ``pruned_eval`` for the protocol and the remaining
+    keyword contracts on ``bm25_topk_dense``.
+
+    ``theta0`` contract (cross-segment threshold sharing): the caller
+    asserts that k results with score >= theta0 are already secured
+    ELSEWHERE (previous segments). Results strictly above theta0 are
+    exact; docs tied at exactly theta0 may be dropped — their slots are
+    covered by the securing results, so a merge over segments is still
+    value-exact vs the force-merged index.
+
+    ``bmw`` selects the doc-range-overlap bound + non-essential list
+    elimination (default) vs the term-level MaxScore baseline;
+    ``midgrid`` additionally runs the survivor scorer through the
+    in-grid theta-tightening kernel when its gates hold (no tombstones,
+    fixed-stride layout, k small enough for the in-kernel fold).
+    """
+    if not prune:
+        return bm25_topk_dense(index, q_terms, k, prune=False, idf_q=idf_q,
+                               doc_norm=doc_norm, max_blocks=max_blocks,
+                               live=live)
+    q_terms = jnp.asarray(q_terms, jnp.int32)
+    idf1 = _resolve_idf(index, q_terms, idf_q)
+    if avgdl is None and doc_norm is None:
+        avgdl = index.avgdl  # baked stats: the self-consistent pair
+
+    def meta(q2d, idf2d):
+        return jax.vmap(
+            lambda q, f: prune_candidates(index, q, f, max_blocks,
+                                          avgdl))(q2d, idf2d)
+
+    def scorer_for(_n):
+        return lambda ci, cf, ca, cr: score_survivors(
+            index, ci, cf, ca, cr, 1, k, doc_norm, live)
+
+    scorer_mid_for = None
+    if midgrid and live is None and not index.compact \
+            and k <= MIDGRID_MAX_K:
+        def scorer_mid_for(_n):
+            return lambda ci, cf, ca, cr, cu, th: score_survivors_midgrid(
+                index, ci, cf, ca, cr, cu, th, 1, k, doc_norm)
+
+    vals, ids, stats = pruned_eval(meta, scorer_for, q_terms[None],
+                                   idf1[None], theta0=theta0, bmw=bmw,
+                                   scorer_mid_for=scorer_mid_for)
+    stats.queries, stats.batches = 1, 1
+    return vals[0], ids[0], {
+        "blocks_scored": stats.blocks_scored,
+        "blocks_survived": stats.blocks_survived,
+        "blocks_total": stats.blocks_candidate,
+        "prune_stats": stats,
+    }
+
+
+# --------------------------------------------------------------------------
+# host oracle of the device bound test (tests compare ``bound_test``
+# against it; nothing on the serving path calls it)
+# --------------------------------------------------------------------------
 
 def _row_searchsorted(keys: np.ndarray, queries: np.ndarray,
                       side: str, stride: int) -> np.ndarray:
@@ -607,16 +945,16 @@ def _bmw_overlap_others(ub3, f3, l3, sentinel: int):
 
 
 def _bound_test(ub, in_term, bf, bl, theta, Q: int, bmw: bool):
-    """Phase 2 of ``pruned_eval`` on host metadata: the blocks that
-    survive the bound test at ``theta``. ``ub``/``in_term`` are (B, Q*MB);
-    ``bf``/``bl`` the blocks' first and last doc ids (read only by
-    ``bmw``). Returns ``(surv (B, Q*MB) bool, bound (B, Q*MB),
-    terms eliminated)``."""
+    """The exact (float64) bound test that ``bound_test`` runs on the
+    device in float32: the blocks that survive at ``theta``.
+    ``ub``/``in_term`` are (B, Q*MB); ``bf``/``bl`` the blocks' first and
+    last doc ids (read only by ``bmw``). Returns ``(surv (B, Q*MB) bool,
+    bound (B, Q*MB), non-essential terms (B, Q) bool)``."""
     B = ub.shape[0]
     ub3 = ub.reshape(B, Q, -1)
     MB = ub3.shape[2]
     term_best = ub3.max(axis=2)                            # (B, Q)
-    n_elim = 0
+    ness = np.zeros((B, Q), bool)
     if bmw:
         # doc-range-overlap "others" bound. Pad entries get a sentinel
         # extent past every real doc id: rows stay sorted (in_term is a
@@ -637,227 +975,14 @@ def _bound_test(ub, in_term, bf, bl, theta, Q: int, bmw: bool):
         # places a winner's remaining contributions can live).
         order = np.argsort(term_best, axis=1, kind="stable")
         csum = np.cumsum(np.take_along_axis(term_best, order, 1), axis=1)
-        ness = np.zeros((B, Q), bool)
         np.put_along_axis(ness, order, csum <= theta[:, None], 1)
-        has_blocks = in3.any(axis=2)
-        n_elim = int((ness & has_blocks).sum())
         if ness.any():
             ess_surv = (base & ~ness[:, :, None]).astype(np.float64)
             touches = _bmw_overlap_others(ess_surv, f3, l3, sentinel) > 0
             base = np.where(ness[:, :, None], base & touches, base)
-        return base.reshape(B, -1), bound3.reshape(B, -1), n_elim
+        return base.reshape(B, -1), bound3.reshape(B, -1), ness
     # term-level MaxScore baseline: every other term helps with its
     # global best block, wherever that block lives in doc space
     others = term_best.sum(axis=1, keepdims=True) - term_best
     bound = (ub3 + others[:, :, None]).reshape(B, -1)
-    return in_term & (bound > theta[:, None]), bound, n_elim
-
-
-def pruned_eval(meta, scorer_for, q2d, idf2d, k: int, theta0=None,
-                n_phase1: int = PHASE1_BLOCKS, bmw: bool = True,
-                scorer_mid_for=None):
-    """Host-orchestrated pruned evaluation over a (B, Q) query batch.
-
-    ``meta(q2d, idf2d)``       -> (ub, in_term, bidx, idf_pb, bfirst,
-                                  blast), (B, Q, MB) device arrays
-                                  (``prune_candidates``, possibly
-                                  jitted/vmapped by the caller).
-    ``scorer_for(n_blocks)``   -> fn(cb_ids, cb_idf, cb_act, cb_row)
-                                  evaluating a flat (n_blocks,) compacted
-                                  survivor list (``score_survivors``) to
-                                  (vals (B, k), ids (B, k)). The caller
-                                  owns jit caching per bucket shape.
-    ``scorer_mid_for``         optional midgrid variant for the SURVIVOR
-                                  stage: fn(cb_ids, cb_idf, cb_act,
-                                  cb_row, cb_ubf, theta_rows) -> (vals,
-                                  ids, n_skipped) — the kernel folds a
-                                  running k-th-best lower bound across
-                                  grid steps and zeroes later blocks
-                                  whose stored full-score UB ``cb_ubf``
-                                  falls below it (see
-                                  ``score_survivors_midgrid``). The
-                                  phase-1 probe always uses the plain
-                                  scorer (theta is not known yet).
-    ``theta0``                 (B,) or scalar: an externally-known lower
-                                  bound on each query's final k-th score
-                                  (the searcher passes the running global
-                                  bound — cross-segment theta sharing).
-    ``bmw``                    True (default) runs the doc-range-overlap
-                                  bound + non-essential list elimination;
-                                  False keeps the term-level MaxScore
-                                  test (the bench A/B baseline).
-
-    Protocol: metadata pass -> host-compact the ``n_phase1`` highest-UB
-    blocks per query and score them for theta (skipped entirely when
-    every query already holds a positive external bound) -> host
-    block-max WAND test at max(theta_phase1, theta0) -> host-compact the
-    survivors (power-of-two bucket over the batch TOTAL) -> compacted
-    exact scoring.
-
-    Exactness under BMW: for a doc d with true score > theta, every block
-    of d survives — the block's own UB majorizes d's contribution from
-    that term, and for every OTHER query term d carries, d's block there
-    shares d and therefore range-overlaps, so its UB enters the overlap
-    sum: bound >= true(d) > theta. Non-essential elimination preserves
-    this: a doc scoring above theta must have at least one essential term
-    (the non-essential prefix's term-best sum is <= theta by
-    construction), its essential blocks survive the bound test, and its
-    non-essential blocks range-overlap one of them — the condition under
-    which non-essential blocks are kept. Docs at or below theta may end
-    up partially scored, but their computed score never exceeds their
-    true score, so any value the final top-k surfaces is exact (ties at
-    theta are covered by the unconditionally-kept phase-1 probes / the
-    ``theta0`` securing contract).
-    Returns ``(vals, ids, PruneStats)``, ``vals``/``ids`` fetched to the
-    host.
-    """
-    B = q2d.shape[0]
-    with span("prune.meta"):
-        ub_d, in_term_d, bidx_d, idf_pb_d, bf_d, bl_d = meta(q2d, idf2d)
-        ub = np.asarray(ub_d, np.float64).reshape(B, -1)
-        in_term = np.asarray(in_term_d).reshape(B, -1)
-        bidx = np.asarray(bidx_d).reshape(B, -1)
-        idf_pb = np.asarray(idf_pb_d).reshape(B, -1)
-        bf = bl = None
-        if bmw:
-            bf, bl = np.asarray(bf_d, np.int64), np.asarray(bl_d, np.int64)
-    n_cand = ub.shape[1]
-    t0 = (np.zeros(B, np.float64) if theta0 is None
-          else np.broadcast_to(np.asarray(theta0, np.float64),
-                               (B,)).astype(np.float64))
-
-    # phase 1: probe the highest-UB blocks for a threshold. The probe set
-    # is compacted too (fixed shape P1), so phase-1 cost is O(P1), not
-    # O(candidates)/2 like the dense oracle's. A caller that already
-    # holds a positive bound for every query (the searcher's shared theta
-    # after the first segment) skips the probe entirely — later segments
-    # pay ONLY for their survivors.
-    probed = 0
-    top = None
-    if not bool(np.all(t0 > 0)):
-        with span("prune.probe"):
-            P1 = min(n_phase1, n_cand)
-            ubm = np.where(in_term, ub, -1.0)
-            top = np.argpartition(-ubm, P1 - 1, axis=1)[:, :P1]
-            p1_act = np.take_along_axis(in_term, top, 1)
-            probed = _pow2ceil(B * P1)
-            p1_ids = np.zeros(probed, np.int32)
-            p1_idf = np.zeros(probed, np.float32)
-            p1_actf = np.zeros(probed, bool)
-            p1_row = np.zeros(probed, np.int32)
-            p1_ids[:B * P1] = np.take_along_axis(bidx, top, 1).reshape(-1)
-            p1_idf[:B * P1] = np.take_along_axis(idf_pb, top, 1).reshape(-1)
-            p1_actf[:B * P1] = p1_act.reshape(-1)
-            p1_row[:B * P1] = np.repeat(np.arange(B, dtype=np.int32), P1)
-            vals1, _ = scorer_for(probed)(p1_ids, p1_idf, p1_actf, p1_row)
-            theta = np.maximum(np.asarray(vals1, np.float64)[:, k - 1], t0)
-    else:
-        theta = t0
-
-    # phase 2, on host metadata. The phase-1 probe blocks are kept
-    # unconditionally either way: the impact bound can be exactly
-    # achieved (the block's best doc IS its (max_tf, min_dl) pair), so a
-    # probed doc at exactly theta must stay scored.
-    with span("prune.bound"):
-        surv, bound, n_elim = _bound_test(ub, in_term, bf, bl, theta,
-                                          q2d.shape[1], bmw)
-        if top is not None:
-            surv[np.arange(B)[:, None], top] |= p1_act
-            # probe blocks carry the unconditional-keep contract into the
-            # midgrid kernel too: their stored UB becomes +inf so the
-            # in-grid skip test can never drop them. (Their host bound
-            # can sit an ulp BELOW theta — f64 bound vs f32 scoring —
-            # which is exactly the tie case the unconditional keep
-            # exists to cover.)
-            rows_b = np.repeat(np.arange(B), top.shape[1])
-            cols_b = top.reshape(-1)
-            keepmask = p1_act.reshape(-1)
-            bound[rows_b[keepmask], cols_b[keepmask]] = np.inf
-        n_surv = int(surv.sum())
-    with span("prune.compact"):
-        cb_ids, cb_idf, cb_act, cb_row, cb_ubf = compact_survivors(
-            surv, bidx, idf_pb, ubf=bound)
-    n_skipped = 0
-    with span("score.survivors"):
-        if scorer_mid_for is not None:
-            vals, ids, n_skip = scorer_mid_for(cb_ids.shape[0])(
-                cb_ids, cb_idf, cb_act, cb_row, cb_ubf,
-                theta.astype(np.float32))
-            n_skipped = int(n_skip)
-        else:
-            vals, ids = scorer_for(cb_ids.shape[0])(cb_ids, cb_idf, cb_act,
-                                                    cb_row)
-        vals, ids = np.asarray(vals), np.asarray(ids)
-    # queries/batches stay zero here: this evaluates ONE segment of a
-    # batch; the caller (searcher / bm25_topk) counts the batch once.
-    stats = PruneStats(
-        segments_visited=1,
-        blocks_candidate=int(in_term.sum()),
-        blocks_survived=n_surv,
-        blocks_scored=probed + cb_ids.shape[0],
-        terms_eliminated=n_elim,
-        blocks_skipped_midgrid=n_skipped)
-    return vals, ids, stats
-
-
-def bm25_topk(index: BlockMaxIndex, q_terms: jnp.ndarray, k: int = 10,
-              prune: bool = True, idf_q=None, doc_norm=None,
-              max_blocks=None, live=None, theta0=None, avgdl=None,
-              bmw: bool = True, midgrid: bool = True):
-    """Top-k BM25: ``(scores (k,), doc_ids (k,), stats dict)``.
-
-    ``prune=True`` runs the compacted pruned path (host-orchestrated, so
-    this function itself is NOT jittable — the searcher caches jitted
-    versions of its two device stages); ``prune=False`` falls back to the
-    dense exhaustive evaluation. Results are identical either way. See
-    ``pruned_eval`` for the protocol and the remaining keyword contracts
-    on ``bm25_topk_dense``.
-
-    ``theta0`` contract (cross-segment threshold sharing): the caller
-    asserts that k results with score >= theta0 are already secured
-    ELSEWHERE (previous segments). Results strictly above theta0 are
-    exact; docs tied at exactly theta0 may be dropped — their slots are
-    covered by the securing results, so a merge over segments is still
-    value-exact vs the force-merged index.
-
-    ``bmw`` selects the doc-range-overlap bound + non-essential list
-    elimination (default) vs the term-level MaxScore baseline;
-    ``midgrid`` additionally runs the survivor scorer through the
-    in-grid theta-tightening kernel when its gates hold (no tombstones,
-    fixed-stride layout, k small enough for the in-kernel fold).
-    """
-    if not prune:
-        return bm25_topk_dense(index, q_terms, k, prune=False, idf_q=idf_q,
-                               doc_norm=doc_norm, max_blocks=max_blocks,
-                               live=live)
-    q_terms = jnp.asarray(q_terms, jnp.int32)
-    idf1 = _resolve_idf(index, q_terms, idf_q)
-    if avgdl is None and doc_norm is None:
-        avgdl = index.avgdl  # baked stats: the self-consistent pair
-
-    def meta(q2d, idf2d):
-        return jax.vmap(
-            lambda q, f: prune_candidates(index, q, f, max_blocks,
-                                          avgdl))(q2d, idf2d)
-
-    def scorer_for(_n):
-        return lambda ci, cf, ca, cr: score_survivors(
-            index, ci, cf, ca, cr, 1, k, doc_norm, live)
-
-    scorer_mid_for = None
-    if midgrid and live is None and not index.compact \
-            and k <= MIDGRID_MAX_K:
-        def scorer_mid_for(_n):
-            return lambda ci, cf, ca, cr, cu, th: score_survivors_midgrid(
-                index, ci, cf, ca, cr, cu, th, 1, k, doc_norm)
-
-    vals, ids, stats = pruned_eval(meta, scorer_for, q_terms[None],
-                                   idf1[None], k, theta0=theta0, bmw=bmw,
-                                   scorer_mid_for=scorer_mid_for)
-    stats.queries, stats.batches = 1, 1
-    return vals[0], ids[0], {
-        "blocks_scored": stats.blocks_scored,
-        "blocks_survived": stats.blocks_survived,
-        "blocks_total": stats.blocks_candidate,
-        "prune_stats": stats,
-    }
+    return in_term & (bound > theta[:, None]), bound, ness

@@ -567,7 +567,11 @@ class SegmentReader:
         ``midgrid``) the theta-tightening scorer variant. Each is one
         compiled function per (kind, statics, shape signature) in the
         process-global cache — jax's shape cache handles the
-        (log2-bounded, bucket-padded) survivor shapes."""
+        (log2-bounded, bucket-padded) survivor shapes. The decision
+        between them (``probe_pick``, ``prune_decide``,
+        ``compact_survivors``) takes no index arrays: one jitted function
+        each, keyed on the metadata's shapes and shared by every
+        segment."""
         mkey = ("meta", max_blocks)
         if mkey not in self._fns:
             def build(statics):
@@ -628,8 +632,9 @@ class SegmentReader:
                     theta0=None, avgdl=None, bmw: bool = True,
                     midgrid: bool = True):
         """Compacted pruned top-k over a (B, Q) batch: metadata pass ->
-        host BMW overlap-bound test (``bmw=False``: term-level MaxScore)
-        at max(phase-1 theta, ``theta0``) -> compacted survivor scoring,
+        device BMW overlap-bound test (``bmw=False``: term-level MaxScore)
+        at max(phase-1 theta, ``theta0``) -> device survivor compaction ->
+        compacted survivor scoring,
         through the midgrid theta-tightening kernel when its gates hold
         (``midgrid`` requested, no tombstones, fixed-stride layout, k
         within the in-kernel fold's budget, batch rows within the
@@ -664,7 +669,7 @@ class SegmentReader:
                     arrs, doc_map, ci, cf, ca, cr, cu, th, doc_norm)
         return pruned_eval(meta, scorer_for,
                            jnp.asarray(q2d, jnp.int32), jnp.asarray(idf2d),
-                           k, theta0=theta0, bmw=bmw,
+                           theta0=theta0, bmw=bmw,
                            scorer_mid_for=scorer_mid_for)
 
 
@@ -885,7 +890,7 @@ class IndexSearcher:
                 top_v = jnp.pad(top_v, ((0, 0), (0, k - kk)))
                 top_i = jnp.pad(top_i, ((0, 0), (0, k - kk)),
                                 constant_values=-1)
-            return np.asarray(top_v), np.asarray(top_i)
+            return jax.device_get((top_v, top_i))
 
     def search(self, q_terms, k: int = 10):
         """Top-k over every live segment; returns (scores (k,), doc_ids (k,))

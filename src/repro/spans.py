@@ -30,10 +30,10 @@ SPANS = {
     "sched.step": "QueryScheduler.step: one batch from the queue to its results",
     "search.plan": "leaf: global idf, per-segment score bounds, visit order",
     "search.segment": "one visited segment of the pruned search",
-    "prune.meta": "leaf: the metadata pass and its fetch",
-    "prune.probe": "leaf: the phase-1 probe: compaction, scorer and its fetch",
-    "prune.bound": "leaf: the BMW or MaxScore bound test and term elimination (host)",
-    "prune.compact": "leaf: compact_survivors (host)",
+    "prune.meta": "leaf: the metadata pass, run to completion on the device (no fetch)",
+    "prune.probe": "leaf: the phase-1 probe on the device: pick, scorer, run to completion (no fetch)",
+    "prune.bound": "leaf: the device bound test, term elimination and probe keep, and the fetch of its counts",
+    "prune.compact": "leaf: compact_survivors, the device gather of the survivors (launch)",
     "score.survivors": "leaf: the survivor scorer (or midgrid) and its fetch",
     "search.merge": "leaf: the cross-segment top-k and its fetch",
 }

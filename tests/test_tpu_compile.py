@@ -1,8 +1,9 @@
-"""Compile rehearsal: the serving path's Pallas kernels, compiled for a
-described (not attached) TPU v5e at the sizes the chip runs, with the
-installed TPU compiler. Nothing runs, so this checks what the chip's
-compiler would refuse (alignment, VMEM, unsupported ops) and that each
-program really contains the Mosaic kernel — not results or times.
+"""Compile rehearsal: the serving path's Pallas kernels and its pruning
+decision, compiled for a described (not attached) TPU v5e at the sizes
+the chip runs, with the installed TPU compiler. Nothing runs, so this
+checks what the chip's compiler would refuse (alignment, VMEM,
+unsupported ops) and that each kernel's program really contains the
+Mosaic kernel — not results or times.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every test
@@ -12,7 +13,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.query import MIDGRID_BLOCK_ROWS
+from repro.core.query import (MIDGRID_BLOCK_ROWS, PHASE1_BLOCKS,
+                              compact_survivors, probe_pick, probe_theta,
+                              prune_decide)
 from repro.kernels.bm25_blockmax.kernel import (bm25_blocks_compact_pallas,
                                                 bm25_blocks_midgrid_pallas,
                                                 bm25_blocks_pallas)
@@ -99,3 +102,21 @@ def test_pack_and_unpack_compile_for_v5e(one_chip):
     i32 = jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=one_chip)
     _compile(lambda d: pack_pallas(d, interpret=False), u32)
     _compile(lambda p, bw: unpack_pallas(p, bw, interpret=False), u32, i32)
+
+
+@pytest.mark.parametrize("bmw", [True, False])
+def test_pruning_decision_compiles_for_v5e(one_chip, bmw):
+    """The device pruning decision at the serving cell's shapes: a batch
+    of 32 queries x 8 terms x 512 candidate blocks, k=1000, a 4096-block
+    survivor bucket. Plain XLA programs, no kernel."""
+    B, Q, MB, k, P1 = 32, 8, 512, 1000, PHASE1_BLOCKS
+    sd = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    f32, i32 = (lambda *s: sd(jnp.float32, *s)), (lambda *s: sd(jnp.int32, *s))
+    b1 = lambda *s: sd(jnp.bool_, *s)
+    ub, in_term, blk = f32(B, Q, MB), b1(B, Q, MB), i32(B, Q, MB)
+    probe_pick.lower(ub, in_term, blk, ub, n_phase1=P1).compile()
+    probe_theta.lower(f32(B, k), f32(B)).compile()
+    prune_decide.lower(ub, in_term, blk, blk, f32(B), i32(B, P1), b1(B, P1),
+                       bmw=bmw).compile()
+    compact_survivors.lower(i32(B, Q * MB), blk, ub, f32(B, Q * MB),
+                            bucket=4096).compile()
